@@ -1,0 +1,7 @@
+"""Seconds from the process's start to the window's start: imports,
+weights, calibration (a file after a checkout's first run), the engine,
+warm-up and, where the cell needs it, its sessions' prefill (host clock)."""
+
+
+def read(run):
+    return run.setup_s

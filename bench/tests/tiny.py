@@ -1,0 +1,39 @@
+"""Runs of the benchmark at a size the CPU runs in seconds: two layers of
+256 wide, heads of the published 128, a vocabulary of 4096, a short
+context. The group is one query head per KV head, so the jnp route the
+CPU serves (per-head selection) and the Pallas kernel's group selection
+agree.
+
+Each case is a traffic mix of ``bench/traffic`` at tiny sizes, served by
+the configuration of cell ``CELL``."""
+import json
+import os
+import time
+
+from bench.lib import harness, spec
+
+CELL = "qwen2.5-3b.longctx_decode"
+CONFIG = {"num_hidden_layers": 2, "hidden_size": 256,
+          "num_attention_heads": 2, "num_key_value_heads": 2,
+          "intermediate_size": 512, "vocab_size": 4096,
+          "serving": {"backend": "xla"}, "calibration": {"length": 256}}
+SIZES = {
+    "longctx_decode": {
+        "sessions": 3, "prompt_len": {"min": 300, "max": 600},
+        "engine": {"n_slots": 3, "smax": 1024}},
+}
+
+
+def mix(case):
+    """The traffic file of ``case`` at its tiny sizes."""
+    with open(os.path.join(spec.BENCH, "traffic", case + ".json")) as f:
+        return harness._merge(json.load(f), SIZES[case])
+
+
+def run(case, seed, seconds=2.0, config=None, traffic=None, **kw):
+    """One run of ``case`` at the tiny size, on whatever JAX finds;
+    ``kw`` goes to ``harness.run`` (``keep``, ``control``)."""
+    over = {"config": harness._merge(CONFIG, config),
+            "traffic": harness._merge(mix(case), traffic)}
+    return harness.run(CELL, seed, seconds, False, t_start=time.time(),
+                       require_chip=False, overrides=over, **kw)
