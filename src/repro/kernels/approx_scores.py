@@ -70,6 +70,7 @@ def block_max_scores(q_hat, k_hat, cur_len, *, d: int, block_size: int = 128,
             out_specs=pl.BlockSpec((1, 1), lambda i, j, ln: (i, j)),
         ),
         out_shape=jax.ShapeDtypeStruct((bh, nb), jnp.float32),
+        name="block_max_scores",
         interpret=interpret,
     )(cur_len.astype(jnp.int32), q_hat, k_hat)
     return out

@@ -77,6 +77,7 @@ def block_max_scores_fm(q_hat, k_hat_T, cur_len, *, d: int,
             out_specs=pl.BlockSpec((1, 1), lambda i, j, ln: (i, j)),
         ),
         out_shape=jax.ShapeDtypeStruct((bh, nb), jnp.float32),
+        name="block_max_scores_fm",
         interpret=interpret,
     )(cur_len.astype(jnp.int32), q_hat, k_hat_T)
     return out

@@ -92,5 +92,6 @@ def flash_attention(q, k, v, *, block_q: int = 128, block_k: int = 128,
             pltpu.VMEM((bq, dim), jnp.float32),
         ],
         out_shape=jax.ShapeDtypeStruct((bh, sq, dim), q.dtype),
+        name="flash_attention",
         interpret=interpret,
     )(q, k, v)

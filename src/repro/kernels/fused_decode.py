@@ -335,6 +335,7 @@ def fused_loki_decode(q_hat, k_hat, v, cur_len, *, d: int, k_blocks: int,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, n_kv, g, dim), q_hat.dtype),
+        name="fused_loki_decode",
         interpret=interpret,
     )(*prefetch, *inputs)
     return out
@@ -413,6 +414,7 @@ def fused_exact_topk_decode(q_hat, k_hat, v, cur_len, *, k_blocks: int,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, n_kv, g, dim), q_hat.dtype),
+        name="fused_exact_topk_decode",
         interpret=interpret,
     )(*prefetch, *inputs)
     return out
@@ -507,6 +509,7 @@ def select_blocks(q_hat, k_hat, cur_len, *, d: int, k_blocks: int,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, n_kv, 1, k_blocks), jnp.int32),
+        name="select_blocks",
         interpret=interpret,
     )(*prefetch, *inputs)
     return out.reshape(b, n_kv, k_blocks)

@@ -106,6 +106,7 @@ def block_sparse_attention(q_hat, k_hat, v, blk_idx, cur_len, *,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((bh, dim), q_hat.dtype),
+        name="block_sparse_attention",
         interpret=interpret,
     )(blk_idx.astype(jnp.int32), cur_len.astype(jnp.int32), q_hat, k_hat, v)
     return out
@@ -293,6 +294,7 @@ def paged_full_decode(q_hat, k_hat, v, cur_len, *, block_size: int = 128,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, n_kv, g, dim), q_hat.dtype),
+        name="paged_full_decode",
         interpret=interpret,
     )(*prefetch, *inputs)
     return out
@@ -464,6 +466,7 @@ def block_sparse_attention_grouped(q_hat, k_hat, v, blk_idx, cur_len, *,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, n_kv, g, dim), q_hat.dtype),
+        name="block_sparse_attention_grouped",
         interpret=interpret,
     )(*prefetch, *inputs)
     return out

@@ -486,6 +486,10 @@ def main():
     if st.get("faults"):
         line += f" faults={st['faults']}"
     print(line)
+    if st.get("tick_phases_ms"):
+        print("tick phases (ms per tick, mean/p95/max): " + ", ".join(
+            f"{k.removeprefix('engine.')} {v['mean']:.2f}/{v['p95']:.2f}/"
+            f"{v['max']:.2f}" for k, v in st["tick_phases_ms"].items()))
     for r in reqs:
         if str(r.status) not in ("done",):
             print(f"  req{r.rid}: {r.status} — {r.detail}")

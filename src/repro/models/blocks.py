@@ -123,6 +123,7 @@ def init_attn_cache(cfg: ModelConfig, batch: int, smax: int, dtype):
 _UINT_OF = {2: jnp.uint16, 4: jnp.uint32, 1: jnp.uint8}
 
 
+@jax.named_scope("pool")
 def _write_cache(cache_arr, new, pos_len):
     """Insert new (B,Hkv,D) rows at per-slot positions pos_len (B,).
 

@@ -407,53 +407,58 @@ def _layer_decode(p, c, x, pos_len, cfg: ModelConfig, kind: str, *,
 
     win = None
     if kind in ("dense", "moe", "hybrid", "dec"):
-        h = L.norm_apply(p["ln1"], x)
-        if frame_table is not None:
-            a, new_attn, win = B.attn_decode(p["attn"], c["attn"], h,
-                                             pos_len, cfg,
-                                             page_table=page_table,
-                                             page_size=page_size,
-                                             frame_table=frame_table,
-                                             rank=rank)
-        else:
-            a, new_attn = B.attn_decode(p["attn"], c["attn"], h, pos_len,
-                                        cfg, page_table=page_table,
-                                        page_size=page_size, rank=rank,
-                                        sliding_window=sliding_window)
-        c = dict(c)
-        c["attn"] = new_attn
-        if kind == "hybrid":
-            s, new_ssm = B.mamba_decode(p["ssm"], c["ssm"], h, cfg)
-            c["ssm"] = keep_live(new_ssm, c["ssm"])
-            a = 0.5 * (L.norm_apply(p["ln_ssm"], a) +
-                       L.norm_apply(p["ln_ssm"], s))
-        x = x + a
-        if kind == "dec" and cfg.is_encoder_decoder:
-            h = L.norm_apply(p["ln_x"], x)
-            from repro.core.attention import decode_full
-            q, _, _ = B._qkv(p["xattn"], h[:, None], cfg)
-            ck, cv = c["cross_k"], c["cross_v"]
-            if "cross_k_scale" in c:      # quantized CrossAttnStatic pages
-                ck = ck.astype(jnp.float32) \
-                    * c["cross_k_scale"][:, None, None, None]
-                cv = cv.astype(jnp.float32) \
-                    * c["cross_v_scale"][:, None, None, None]
-            o = decode_full(q[:, 0], ck, cv, jnp.int32(ck.shape[1]))
-            x = x + L.dot(o.reshape(x.shape[0], cfg.q_dim),
-                          p["xattn"]["wo"].astype(x.dtype))
-        h = L.norm_apply(p["ln2"], x)
-        y = (B.moe_decode(p["moe"], h, cfg) if kind == "moe"
-             else L.mlp_apply(p["mlp"], h, cfg))
-        x = x + y
+        with jax.named_scope("attn"):
+            h = L.norm_apply(p["ln1"], x)
+            if frame_table is not None:
+                a, new_attn, win = B.attn_decode(p["attn"], c["attn"], h,
+                                                 pos_len, cfg,
+                                                 page_table=page_table,
+                                                 page_size=page_size,
+                                                 frame_table=frame_table,
+                                                 rank=rank)
+            else:
+                a, new_attn = B.attn_decode(p["attn"], c["attn"], h,
+                                            pos_len, cfg,
+                                            page_table=page_table,
+                                            page_size=page_size, rank=rank,
+                                            sliding_window=sliding_window)
+            c = dict(c)
+            c["attn"] = new_attn
+            if kind == "hybrid":
+                s, new_ssm = B.mamba_decode(p["ssm"], c["ssm"], h, cfg)
+                c["ssm"] = keep_live(new_ssm, c["ssm"])
+                a = 0.5 * (L.norm_apply(p["ln_ssm"], a) +
+                           L.norm_apply(p["ln_ssm"], s))
+            x = x + a
+            if kind == "dec" and cfg.is_encoder_decoder:
+                h = L.norm_apply(p["ln_x"], x)
+                from repro.core.attention import decode_full
+                q, _, _ = B._qkv(p["xattn"], h[:, None], cfg)
+                ck, cv = c["cross_k"], c["cross_v"]
+                if "cross_k_scale" in c:  # quantized CrossAttnStatic pages
+                    ck = ck.astype(jnp.float32) \
+                        * c["cross_k_scale"][:, None, None, None]
+                    cv = cv.astype(jnp.float32) \
+                        * c["cross_v_scale"][:, None, None, None]
+                o = decode_full(q[:, 0], ck, cv, jnp.int32(ck.shape[1]))
+                x = x + L.dot(o.reshape(x.shape[0], cfg.q_dim),
+                              p["xattn"]["wo"].astype(x.dtype))
+        with jax.named_scope("mlp"):
+            h = L.norm_apply(p["ln2"], x)
+            y = (B.moe_decode(p["moe"], h, cfg) if kind == "moe"
+                 else L.mlp_apply(p["mlp"], h, cfg))
+            x = x + y
     else:
-        h = L.norm_apply(p["ln1"], x)
-        fn = B.mlstm_decode if kind == "mlstm" else B.slstm_decode
-        y, new_ssm = fn(p["ssm"], c["ssm"], h, cfg)
-        c = dict(c)
-        c["ssm"] = keep_live(new_ssm, c["ssm"])
-        x = x + y
-        h = L.norm_apply(p["ln2"], x)
-        x = x + L.mlp_apply(p["mlp"], h, cfg)
+        with jax.named_scope("attn"):
+            h = L.norm_apply(p["ln1"], x)
+            fn = B.mlstm_decode if kind == "mlstm" else B.slstm_decode
+            y, new_ssm = fn(p["ssm"], c["ssm"], h, cfg)
+            c = dict(c)
+            c["ssm"] = keep_live(new_ssm, c["ssm"])
+            x = x + y
+        with jax.named_scope("mlp"):
+            h = L.norm_apply(p["ln2"], x)
+            x = x + L.mlp_apply(p["mlp"], h, cfg)
     return x, c, win
 
 
@@ -464,6 +469,7 @@ _UINT_OF = {1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32}
 _SLOT_KEYS = ("ssm", "cross_k", "cross_v", "cross_k_scale", "cross_v_scale")
 
 
+@jax.named_scope("pool")
 def _slot_gather(layers, sidx, scan: bool):
     """Compact the per-slot cache components to the packed batch: leaf
     [n_slots] rows -> [n_live] rows at ``sidx``. Pooled attn leaves pass
@@ -480,6 +486,7 @@ def _slot_gather(layers, sidx, scan: bool):
             for lc in layers]
 
 
+@jax.named_scope("pool")
 def _slot_scatter(full_layers, packed_layers, sidx, scan: bool):
     """Merge a packed decode's cache back into the full-width cache.
 
@@ -507,6 +514,7 @@ def _slot_scatter(full_layers, packed_layers, sidx, scan: bool):
     return [merge(f, p, 0) for f, p in zip(full_layers, packed_layers)]
 
 
+@jax.named_scope("pool")
 def _cache_bits(tree):
     """Float leaves -> same-width uint views (free bitcast on TPU). The scan
     then slices/stacks the per-layer cache with *integer* dynamic-slice /
@@ -521,6 +529,7 @@ def _cache_bits(tree):
     return jax.tree.map(f, tree)
 
 
+@jax.named_scope("pool")
 def _cache_unbits(tree, dtypes):
     return jax.tree.map(
         lambda a, dt: jax.lax.bitcast_convert_type(a, dt)
@@ -557,12 +566,21 @@ def decode_step(params, cfg: ModelConfig, cache, token, pos_len, *,
     pages). The return becomes (logits, winners, new_cache) where
     ``winners (B, max_pages)`` bool is the union over layers of logical
     pages the Loki selection attended — the scheduler promotes HOST
-    winners and replays."""
-    x = L.embed_apply(params["embed"], token[:, None], cfg)[:, 0]
-    if not cfg.rope and cfg.family != "ssm":
-        # sinusoidal decoders: add position encoding for the current slot
-        d = cfg.d_model
-        x = x + _sinusoidal_at(pos_len, d).astype(x.dtype)
+    winners and replays.
+
+    Device scopes (``jax.named_scope``, in the ops' metadata): ``pool``
+    moves the page pool (the layer scan's cache carry and u16 views, the
+    packed slot gather/scatter, K/V row writes and logical gathers),
+    ``attn`` the token mixer (norm, QKV, rope, PCA projection, the
+    attention kernel or its jnp route, output projection), ``mlp`` the
+    feed-forward, ``head`` the embedding lookup, final norm and LM head."""
+    with jax.named_scope("head"):
+        x = L.embed_apply(params["embed"], token[:, None], cfg)[:, 0]
+        if not cfg.rope and cfg.family != "ssm":
+            # sinusoidal decoders: add position encoding for the current
+            # slot
+            d = cfg.d_model
+            x = x + _sinusoidal_at(pos_len, d).astype(x.dtype)
 
     tiered = frame_table is not None
     ranks = None
@@ -600,12 +618,15 @@ def decode_step(params, cfg: ModelConfig, cache, token, pos_len, *,
                 return (x, win | w), _cache_bits(c)
             return x, _cache_bits(c)
 
-        if tiered:
-            win0 = jnp.zeros(page_table.shape, bool)
-            (x, win), new_bits = jax.lax.scan(body, (x, win0), xs)
-        else:
-            win = None
-            x, new_bits = jax.lax.scan(body, x, xs)
+        # the scan's per-layer slicing and restacking of the cache is
+        # the pool's (the body's own ops carry their inner scopes)
+        with jax.named_scope("pool"):
+            if tiered:
+                win0 = jnp.zeros(page_table.shape, bool)
+                (x, win), new_bits = jax.lax.scan(body, (x, win0), xs)
+            else:
+                win = None
+                x, new_bits = jax.lax.scan(body, x, xs)
         new_cache = {"layers": _cache_unbits(new_bits, dtypes)}
     elif hetero:
         # per-layer static windows: unroll over the stacked leaves so each
@@ -618,7 +639,8 @@ def decode_step(params, cfg: ModelConfig, cache, token, pos_len, *,
         new_layers = layers_in
         for i in range(cfg.n_layers):
             p = jax.tree.map(lambda a: a[i], params["layers"])
-            c = jax.tree.map(lambda a: a[i], new_layers)
+            with jax.named_scope("pool"):
+                c = jax.tree.map(lambda a: a[i], new_layers)
             pt_i = page_table
             if page_table is not None and page_table.ndim == 3:
                 pt_i = page_table[:, gids[i]]
@@ -627,9 +649,10 @@ def decode_step(params, cfg: ModelConfig, cache, token, pos_len, *,
                 page_table=pt_i, page_size=page_size, live=live,
                 rank=None if ranks is None else ranks[i],
                 sliding_window=cfg.layer_window(i))
-            new_layers = jax.tree.map(
-                lambda full, one: jax.lax.dynamic_update_index_in_dim(
-                    full, one.astype(full.dtype), i, 0), new_layers, c)
+            with jax.named_scope("pool"):
+                new_layers = jax.tree.map(
+                    lambda full, one: jax.lax.dynamic_update_index_in_dim(
+                        full, one.astype(full.dtype), i, 0), new_layers, c)
         new_cache = {"layers": new_layers}
     else:
         # non-scan families (xlstm) have no paged attention: no tiering
@@ -651,8 +674,9 @@ def decode_step(params, cfg: ModelConfig, cache, token, pos_len, *,
         new_cache = {"layers": _slot_scatter(cache["layers"],
                                              new_cache["layers"],
                                              sidx, scan)}
-    x = L.norm_apply(params["final_norm"], x)
-    logits = L.unembed_apply(params["embed"], x[:, None], cfg)[:, 0]
+    with jax.named_scope("head"):
+        x = L.norm_apply(params["final_norm"], x)
+        logits = L.unembed_apply(params["embed"], x[:, None], cfg)[:, 0]
     if tiered:
         return logits, win, new_cache
     return logits, new_cache
@@ -780,7 +804,10 @@ def prefill_chunk(params, cfg: ModelConfig, cache, tokens, pos_start,
     entry. Prefill is exact attention over the whole prefix, so the
     scheduler promotes *all* of the slot's pages before each chunk; here
     the frame row simply redirects the K/V writes and gathers while the
-    latent sidecar is written through the logical ``table_row``."""
+    latent sidecar is written through the logical ``table_row``.
+
+    Device scopes as in ``decode_step`` (``pool`` also takes the slot's
+    recurrent-state slice and update)."""
     CS.assert_pageable(cfg)
     if cfg.window_layers is not None:
         # per-layer table groups: the table is (n_groups, max_pages) (or
@@ -794,14 +821,19 @@ def prefill_chunk(params, cfg: ModelConfig, cache, tokens, pos_start,
              if cfg.page_ranks is not None else None)
     slot = jnp.int32(0) if slot is None else jnp.asarray(slot, jnp.int32)
     b, c = tokens.shape
-    x = L.embed_apply(params["embed"], tokens, cfg)
     positions = pos_start + jnp.arange(c)
-    if (not cfg.rope or cfg.is_encoder_decoder) and cfg.family != "ssm":
-        x = x + _sinusoidal_at(positions, cfg.d_model)[None].astype(x.dtype)
+    with jax.named_scope("head"):
+        x = L.embed_apply(params["embed"], tokens, cfg)
+        if (not cfg.rope or cfg.is_encoder_decoder) \
+                and cfg.family != "ssm":
+            x = x + _sinusoidal_at(positions, cfg.d_model)[None].astype(
+                x.dtype)
 
+    @jax.named_scope("pool")
     def slot_take(a):
         return jax.lax.dynamic_slice_in_dim(a, slot, 1, axis=0)
 
+    @jax.named_scope("pool")
     def slot_put(full, one):
         return jax.lax.dynamic_update_slice_in_dim(
             full, one.astype(full.dtype), slot, axis=0)
@@ -811,44 +843,44 @@ def prefill_chunk(params, cfg: ModelConfig, cache, tokens, pos_start,
 
         def body_at(x, p, cc, rk, trow, sw):
             cc = dict(cc)
-            h = L.norm_apply(p["ln1"], x)
-            a, new_attn = B.attn_prefill_chunk(p["attn"], cc["attn"], h,
-                                               pos_start, n_valid, cfg,
-                                               table_row=trow,
-                                               page_size=page_size,
-                                               frame_row=frame_row,
-                                               rank=rk,
-                                               sliding_window=sw)
-            cc["attn"] = new_attn
-            if kind == "hybrid":
-                st = jax.tree.map(slot_take, cc["ssm"])
-                sy, new_st = B.mamba_prefill_chunk(p["ssm"], st, h,
-                                                   n_valid, cfg)
-                cc["ssm"] = jax.tree.map(slot_put, cc["ssm"], new_st)
-                a = 0.5 * (L.norm_apply(p["ln_ssm"], a) +
-                           L.norm_apply(p["ln_ssm"], sy))
-            x = x + a
-            if kind == "dec" and cfg.is_encoder_decoder:
-                ek = slot_take(cc["cross_k"])
-                ev = slot_take(cc["cross_v"])
-                if "cross_k_scale" in cc:
-                    ek = ek.astype(jnp.float32) \
-                        * slot_take(cc["cross_k_scale"])[:, None, None, None]
-                    ev = ev.astype(jnp.float32) \
-                        * slot_take(cc["cross_v_scale"])[:, None, None, None]
-                ek, ev = ek.astype(x.dtype), ev.astype(x.dtype)
-                hx = L.norm_apply(p["ln_x"], x)
-                q, _, _ = B._qkv(p["xattn"], hx, cfg)
-                from repro.core.attention import cross_attention
-                o = cross_attention(q, ek, ev)
-                x = x + L.dot(o.reshape(b, c, cfg.q_dim),
-                              p["xattn"]["wo"].astype(x.dtype))
-            h = L.norm_apply(p["ln2"], x)
-            if kind == "moe":
-                y, _ = B.moe_apply(p["moe"], h, cfg)
-            else:
-                y = L.mlp_apply(p["mlp"], h, cfg)
-            return x + y, cc
+            with jax.named_scope("attn"):
+                h = L.norm_apply(p["ln1"], x)
+                a, new_attn = B.attn_prefill_chunk(
+                    p["attn"], cc["attn"], h, pos_start, n_valid, cfg,
+                    table_row=trow, page_size=page_size,
+                    frame_row=frame_row, rank=rk, sliding_window=sw)
+                cc["attn"] = new_attn
+                if kind == "hybrid":
+                    st = jax.tree.map(slot_take, cc["ssm"])
+                    sy, new_st = B.mamba_prefill_chunk(p["ssm"], st, h,
+                                                       n_valid, cfg)
+                    cc["ssm"] = jax.tree.map(slot_put, cc["ssm"], new_st)
+                    a = 0.5 * (L.norm_apply(p["ln_ssm"], a) +
+                               L.norm_apply(p["ln_ssm"], sy))
+                x = x + a
+                if kind == "dec" and cfg.is_encoder_decoder:
+                    ek = slot_take(cc["cross_k"])
+                    ev = slot_take(cc["cross_v"])
+                    if "cross_k_scale" in cc:
+                        ek = ek.astype(jnp.float32) * slot_take(
+                            cc["cross_k_scale"])[:, None, None, None]
+                        ev = ev.astype(jnp.float32) * slot_take(
+                            cc["cross_v_scale"])[:, None, None, None]
+                    ek, ev = ek.astype(x.dtype), ev.astype(x.dtype)
+                    hx = L.norm_apply(p["ln_x"], x)
+                    q, _, _ = B._qkv(p["xattn"], hx, cfg)
+                    from repro.core.attention import cross_attention
+                    o = cross_attention(q, ek, ev)
+                    x = x + L.dot(o.reshape(b, c, cfg.q_dim),
+                                  p["xattn"]["wo"].astype(x.dtype))
+            with jax.named_scope("mlp"):
+                h = L.norm_apply(p["ln2"], x)
+                if kind == "moe":
+                    y, _ = B.moe_apply(p["moe"], h, cfg)
+                else:
+                    y = L.mlp_apply(p["mlp"], h, cfg)
+                y = x + y
+            return y, cc
 
         if cfg.window_layers is not None:
             # unrolled: each layer's window is static and its K/V scatter
@@ -857,16 +889,19 @@ def prefill_chunk(params, cfg: ModelConfig, cache, tokens, pos_start,
             new_layers = cache["layers"]
             for i in range(cfg.n_layers):
                 p = jax.tree.map(lambda a: a[i], params["layers"])
-                cc = jax.tree.map(lambda a: a[i], new_layers)
+                with jax.named_scope("pool"):
+                    cc = jax.tree.map(lambda a: a[i], new_layers)
                 trow = (table_row[gids[i]] if table_row.ndim == 2
                         else table_row)
                 x, cc = body_at(x, p, cc,
                                 None if ranks is None else ranks[i],
                                 trow, cfg.layer_window(i))
-                new_layers = jax.tree.map(
-                    lambda full, one: jax.lax.dynamic_update_index_in_dim(
-                        full, one.astype(full.dtype), i, 0),
-                    new_layers, cc)
+                with jax.named_scope("pool"):
+                    new_layers = jax.tree.map(
+                        lambda full, one:
+                        jax.lax.dynamic_update_index_in_dim(
+                            full, one.astype(full.dtype), i, 0),
+                        new_layers, cc)
             new_cache = {"layers": new_layers}
         else:
             xs = (params["layers"], cache["layers"])
@@ -877,7 +912,9 @@ def prefill_chunk(params, cfg: ModelConfig, cache, tokens, pos_start,
                 rk = pc[2] if len(pc) > 2 else None
                 return body_at(x, pc[0], pc[1], rk, table_row, None)
 
-            x, new_layers = jax.lax.scan(body, x, xs)
+            # the scan's per-layer cache slicing and restacking
+            with jax.named_scope("pool"):
+                x, new_layers = jax.lax.scan(body, x, xs)
             new_cache = {"layers": new_layers}
     else:
         # ssm family (xlstm): no pages at all — the chunk runs the
@@ -888,20 +925,23 @@ def prefill_chunk(params, cfg: ModelConfig, cache, tokens, pos_start,
             p = params["layers"][i]
             cc = dict(cache["layers"][i])
             st = jax.tree.map(slot_take, cc["ssm"])
-            h = L.norm_apply(p["ln1"], x)
-            fn = B.mlstm_train if kind == "mlstm" else B.slstm_train
-            y, new_st = fn(p["ssm"], h, cfg, return_state=True,
-                           initial_state=st, n_valid=n_valid)
+            with jax.named_scope("attn"):
+                h = L.norm_apply(p["ln1"], x)
+                fn = B.mlstm_train if kind == "mlstm" else B.slstm_train
+                y, new_st = fn(p["ssm"], h, cfg, return_state=True,
+                               initial_state=st, n_valid=n_valid)
+                x = x + y
             cc["ssm"] = jax.tree.map(slot_put, cc["ssm"], new_st)
             new_list.append(cc)
-            x = x + y
-            h2 = L.norm_apply(p["ln2"], x)
-            x = x + L.mlp_apply(p["mlp"], h2, cfg)
+            with jax.named_scope("mlp"):
+                h2 = L.norm_apply(p["ln2"], x)
+                x = x + L.mlp_apply(p["mlp"], h2, cfg)
         new_cache = {"layers": new_list}
 
-    x_last = jax.lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=1)
-    x_last = L.norm_apply(params["final_norm"], x_last)
-    logits = L.unembed_apply(params["embed"], x_last, cfg)[:, 0]
+    with jax.named_scope("head"):
+        x_last = jax.lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=1)
+        x_last = L.norm_apply(params["final_norm"], x_last)
+        logits = L.unembed_apply(params["embed"], x_last, cfg)[:, 0]
     return logits, new_cache
 
 

@@ -108,10 +108,11 @@ def sample_next(logits, *, greedy: bool, rng, ticks: int):
     """Shared next-token rule for both engines: greedy argmax, or
     categorical with the caller's key (falling back to PRNGKey(tick) —
     thread a real rng via run_until_done for independent draws)."""
-    if greedy:
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    rng = rng if rng is not None else jax.random.PRNGKey(ticks)
-    return jax.random.categorical(rng, logits).astype(jnp.int32)
+    with jax.named_scope("head"):
+        if greedy:
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        rng = rng if rng is not None else jax.random.PRNGKey(ticks)
+        return jax.random.categorical(rng, logits).astype(jnp.int32)
 
 
 class ServingEngine:

@@ -95,6 +95,7 @@ ROOT_KEY = b""
 
 
 # ------------------------------------------------------------ jnp helpers
+# (those that write or read pool rows run under the device scope ``pool``)
 
 def logical_rows(page_table, page_size: int):
     """(B, max_pages) int32 -> (B, max_pages * page_size) pool row ids."""
@@ -143,11 +144,13 @@ def token_rows(page_table, pos, page_size: int):
     return pid * page_size + (pos % page_size).astype(jnp.int32)
 
 
+@jax.named_scope("pool")
 def write_token_rows(pool, new, page_table, pos, page_size: int):
     """Decode-step write: new (B, Hkv, W) at logical positions pos (B,)."""
     return _scatter_rows(pool, token_rows(page_table, pos, page_size), new)
 
 
+@jax.named_scope("pool")
 def write_chunk_rows(pool, new, table_row, pos_start, page_size: int, *,
                      n_valid=None):
     """Chunked-prefill write: new (C, Hkv, W) at logical positions
@@ -204,6 +207,7 @@ def gather_scales(scales, page_table, page_size: int):
     return jnp.repeat(s, page_size, axis=1)
 
 
+@jax.named_scope("pool")
 def gather_logical_dq(pool, scales, page_table, page_size: int):
     """``gather_logical`` + dequantization: the f32 logical view of a
     quantized pool (``scales=None`` falls through to the plain gather, so
@@ -215,6 +219,7 @@ def gather_logical_dq(pool, scales, page_table, page_size: int):
     return rows.astype(jnp.float32) * s[:, :, None, None]
 
 
+@jax.named_scope("pool")
 def write_token_rows_q(pool, scales, new, page_table, pos, page_size: int,
                        *, qmax: float):
     """Quantized decode-step write: RMW of each slot's current page.
@@ -241,6 +246,7 @@ def write_token_rows_q(pool, scales, new, page_table, pos, page_size: int,
     return jax.lax.fori_loop(0, new.shape[0], body, (pool, scales))
 
 
+@jax.named_scope("pool")
 def write_chunk_rows_q(pool, scales, new, table_row, pos_start,
                        page_size: int, *, n_valid=None, qmax: float):
     """Quantized chunked-prefill write (one request): RMW of every page
@@ -278,6 +284,7 @@ def write_chunk_rows_q(pool, scales, new, table_row, pos_start,
     return jax.lax.fori_loop(0, span, body, (pool, scales))
 
 
+@jax.named_scope("pool")
 def copy_page_rows(pool, src_page, dst_page, page_size: int):
     """Copy-on-write: duplicate one physical page's rows inside a pool.
 

@@ -71,6 +71,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import re
 import time
 from typing import Any, Deque, Dict, List, Optional, Tuple
 
@@ -91,6 +92,7 @@ from repro.serving.engine import (Request, context_cap, oversized_reason,
 from repro.serving.lifecycle import Status
 from repro.serving.paged_cache import PagePool
 from repro.serving.policy import SchedulerPolicy, TickBudget, make_policy
+from repro.serving.tracing import TickLog
 
 PAGED_POLICIES = ("full", "exact_topk", "loki", "loki_block")
 # paged policies whose decode runs the Pallas kernels under backend pallas
@@ -102,6 +104,14 @@ _KERNEL_POLICIES = ("full", "exact_topk", "loki_block")
 # every observed trace — 4 leaves one run of slack before declaring
 # promotion/selection ping-pong
 _TIERED_MAX_RUNS = 4
+
+
+def _named(name: str, fn):
+    """Give a program's traced function ``name`` (``decode_step``,
+    ``decode_step_packed_b8``), so its compiled program is
+    ``jit_<name>`` in the profiler trace instead of ``jit__lambda_``."""
+    fn.__name__ = fn.__qualname__ = re.sub(r"\W+", "_", name).strip("_")
+    return fn
 
 
 def _dus(full, one, slot, axis):
@@ -267,6 +277,9 @@ class PagedServingEngine:
                              "use 'strict' or 'lenient'")
         self.admission = admission
         self._clock = clock or time.time
+        # per tick: each phase's milliseconds and the tick's counters
+        # (serving/tracing.py), inside profiler spans of the same names
+        self.tick_log = TickLog(self._clock)
         self.shed_after = shed_after
         self._faults = faults
         self.audit = audit
@@ -354,6 +367,7 @@ class PagedServingEngine:
         self.n_recycled_pages = 0
         self.peak_slot_pages = 0       # max pages any slot held at once
         self.n_prefill_computed_tokens = 0
+        self.n_pages_grown = 0
         self.n_cow_copies = 0
         self.n_state_restores = 0
         # tiered-pool engine state (DESIGN.md §13): host byte buffers for
@@ -392,7 +406,7 @@ class PagedServingEngine:
         guard = self._trace_guard
         if guard is not None:
             guard.rebuild()     # legitimate retrace window re-opens
-        wrap = guard.wrap if guard is not None else (lambda _n, f: f)
+        wrap = self._wrap
         # the cache argument is donated on every cache-updating program:
         # the caller always replaces ``self.cache`` with the result, so
         # the old buffer is dead on return and XLA may update in place
@@ -401,8 +415,8 @@ class PagedServingEngine:
         # before dispatch ever consumes the buffer)
         self._decode = jax.jit(
             wrap("decode_step",
-                 lambda p, c, t, pl, pt, lv: lm.decode_step(
-                     p, cfg, c, t, pl, page_table=pt, page_size=ps,
+                 lambda p, pool, t, pl, pt, lv: lm.decode_step(
+                     p, cfg, pool, t, pl, page_table=pt, page_size=ps,
                      live=lv)),
             donate_argnums=(1,) if self._donate else ())
         # per-bucket packed decode programs jit lazily (_packed_program);
@@ -411,35 +425,37 @@ class PagedServingEngine:
         self._decode_packed: Dict[int, Any] = {}
         self._chunk = jax.jit(
             wrap("prefill_chunk",
-                 lambda p, c, toks, start, nv, row, sl: lm.prefill_chunk(
-                     p, cfg, c, toks, start, nv, row, ps, slot=sl)),
+                 lambda p, pool, toks, start, nv, row, sl:
+                 lm.prefill_chunk(p, cfg, pool, toks, start, nv, row, ps,
+                                  slot=sl)),
             donate_argnums=(1,) if self._donate else ())
         self._copy_page = jax.jit(
             wrap("copy_cache_page",
-                 lambda c, s, d: lm.copy_cache_page(cfg, c, s, d, ps)),
+                 lambda pool, s, d: lm.copy_cache_page(cfg, pool, s, d,
+                                                       ps)),
             donate_argnums=(0,) if self._donate else ())
         if self.tiered:
             self._decode_t = jax.jit(
                 wrap("decode_step_tiered",
-                     lambda p, c, t, pl, pt, ft, lv: lm.decode_step(
-                         p, cfg, c, t, pl, page_table=pt, page_size=ps,
+                     lambda p, pool, t, pl, pt, ft, lv: lm.decode_step(
+                         p, cfg, pool, t, pl, page_table=pt, page_size=ps,
                          live=lv, frame_table=ft)),
                 donate_argnums=(1,) if self._donate else ())
             self._chunk_t = jax.jit(
                 wrap("prefill_chunk_tiered",
-                     lambda p, c, toks, start, nv, row, fr, sl:
-                     lm.prefill_chunk(p, cfg, c, toks, start, nv, row,
+                     lambda p, pool, toks, start, nv, row, fr, sl:
+                     lm.prefill_chunk(p, cfg, pool, toks, start, nv, row,
                                       ps, slot=sl, frame_row=fr)),
                 donate_argnums=(1,) if self._donate else ())
             self._copy_page_t = jax.jit(
                 wrap("copy_cache_page_tiered",
-                     lambda c, s, d, sf, df: lm.copy_cache_page(
-                         cfg, c, s, d, ps, src_frame=sf, dst_frame=df)),
+                     lambda pool, s, d, sf, df: lm.copy_cache_page(
+                         cfg, pool, s, d, ps, src_frame=sf, dst_frame=df)),
                 donate_argnums=(0,) if self._donate else ())
             self._promote_write = jax.jit(
                 wrap("promote_page_rows",
-                     lambda c, k, v, f: lm.promote_page_rows(
-                         cfg, c, k, v, f, ps)),
+                     lambda pool, k, v, f: lm.promote_page_rows(
+                         cfg, pool, k, v, f, ps)),
                 donate_argnums=(0,) if self._donate else ())
             if self._fresh_state is not None:
                 # batched rewind for the miss-repair re-run: one masked
@@ -457,6 +473,19 @@ class PagedServingEngine:
             self._encode_cross = jax.jit(
                 lambda p, fr: lm.encode_cross_kv(p, cfg, fr))
 
+    def _wrap(self, name: str, fn):
+        """A program's traced function: counted by the trace guard, if
+        any, and named ``name``. Its arguments keep ``fn``'s names, so
+        the ops XLA inserts on the cache argument (layout copies) carry
+        ``pool`` in their metadata, like the ops under the device scope
+        ``pool`` (models/lm.py)."""
+        guard = self._trace_guard
+        if guard is None:
+            return _named(name, fn)
+        traced = guard.wrap(name, fn)
+        traced.__wrapped__ = fn
+        return _named(name, traced)
+
     def _packed_program(self, bucket: int):
         """The packed decode program for one bucket width, jitted on
         first use — or None when the trace guard is sealed and this
@@ -470,12 +499,11 @@ class PagedServingEngine:
         if guard is not None and guard.sealed \
                 and not guard.traces.get(name):
             return None
-        cfg, ps = self.cfg, self.page_size
-        wrap = guard.wrap if guard is not None else (lambda _n, f: f)
+        cfg, ps, wrap = self.cfg, self.page_size, self._wrap
         prog = jax.jit(
             wrap(name,
-                 lambda p, c, t, pl, pt, lv, si: lm.decode_step(
-                     p, cfg, c, t, pl, page_table=pt, page_size=ps,
+                 lambda p, pool, t, pl, pt, lv, si: lm.decode_step(
+                     p, cfg, pool, t, pl, page_table=pt, page_size=ps,
                      live=lv, slot_idx=si)),
             donate_argnums=(1,) if self._donate else ())
         self._decode_packed[bucket] = prog
@@ -939,6 +967,7 @@ class PagedServingEngine:
             base = len(plist)
             table[slot, base:base + need] = pages
             plist.extend(pages)
+            self.n_pages_grown += need
         self.peak_slot_pages = max(
             self.peak_slot_pages,
             sum(p is not None for p in self.slot_pages[slot]))
@@ -1273,6 +1302,7 @@ class PagedServingEngine:
 
         Returns (nxt, finite, committed) over the full slot axis, with
         ``committed`` <= the ``sel`` passed in."""
+        log = self.tick_log
         todo = sel.copy()
         done = np.zeros_like(sel)
         nxt_out = np.zeros((self.n_slots,), np.int64)
@@ -1283,78 +1313,92 @@ class PagedServingEngine:
         # advances exactly once no matter how many runs it took
         snap = None
         if self._fresh_state is not None:
-            layers = self.cache["layers"]
-            snap = {k: jax.tree.map(jnp.copy, layers[k])
-                    for k in self._fresh_state}
-        for attempt in range(_TIERED_MAX_RUNS):
-            ran = todo.copy()
-            sel_dev = jnp.asarray(todo)
-            pt = self.page_table * todo.astype(np.int32)[:, None]
-            ft = self._frame_table(pt)
-            logits, win, self.cache = self._run_decode_t(pt, ft, sel_dev)
-            if self._faults is not None:
-                bad = [s for s in np.flatnonzero(todo)
-                       if self._faults.hit("nan_logits", int(s))]
-                if bad:
-                    logits = logits.at[
-                        jnp.asarray(bad, jnp.int32)].set(jnp.nan)
-            finite_dev = jnp.isfinite(logits).all(axis=-1) \
-                if self.nan_guard else None
-            nxt = sample_next(logits, greedy=self.greedy, rng=rng,
-                              ticks=self.ticks)
-            # host-sync: the ONE batched device->host sync of the common
-            # (all-hit) tiered tick — sampled tokens, the nan-guard mask
-            # and the winner mask cross together
-            nxt_np, finite, win_np = jax.device_get(
-                (nxt, finite_dev, win))
-            winners = self._winner_pages(pt, np.asarray(win_np), todo)
-            miss = {s: [p for p in sorted(pages)
-                        if self.pool.tier_of(p) != PC.RESIDENT]
-                    for s, pages in winners.items()}
-            miss = {s: ps_ for s, ps_ in miss.items() if ps_}
-            if attempt == 0:
-                uniq = set().union(*winners.values()) if winners else set()
-                n_miss = sum(self.pool.tier_of(p) != PC.RESIDENT
-                             for p in uniq)
-                self.n_prefetch_misses += n_miss
-                self.n_prefetch_hits += len(uniq) - n_miss
-            # commit every fully-resident slot: its token is exact, its
-            # K/V row write is input-only (valid even beside garbage
-            # reads), and its advanced state must NOT be restored
-            for s in winners:
-                if s in miss:
-                    continue
-                done[s] = True
-                todo[s] = False
-                nxt_out[s] = nxt_np[s]
-                if fin_out is not None:
-                    fin_out[s] = bool(finite[s])
-                for p in winners[s]:
-                    self._page_last_use[p] = self.ticks
-            if todo.any():
-                self.n_decode_reruns += 1
-                self._repair_misses(miss, winners, todo)
-                self._fetch.drain()
-                self._prune_host()
-            # restore every slot that ran this attempt without
-            # committing — both the re-running and the just-deferred:
-            # their recurrent state advanced on garbage attention inputs
-            # and must rewind to the snapshot (committed slots keep
-            # theirs, so each stream's state advances exactly once)
-            stale = ran & ~done
-            if snap is not None and stale.any():
-                # one jitted masked restore over every stale slot at once
-                # (was a per-slot snapshot/reset Python loop: a chain of
-                # eagerly-dispatched slice updates per re-run)
+            with log.phase("engine.decode.prep"):
                 layers = self.cache["layers"]
-                sub = {k: layers[k] for k in snap}
-                sub = self._rewind(sub, snap, jnp.asarray(stale))
-                self.cache = {"layers": {**layers, **sub}}
+                snap = {k: jax.tree.map(jnp.copy, layers[k])
+                        for k in self._fresh_state}
+        for attempt in range(_TIERED_MAX_RUNS):
+            with log.phase("engine.decode.prep"):
+                ran = todo.copy()
+                sel_dev = jnp.asarray(todo)
+                pt = self.page_table * todo.astype(np.int32)[:, None]
+                ft = self._frame_table(pt)
+            with log.phase("engine.decode.launch"):
+                logits, win, self.cache = self._run_decode_t(pt, ft,
+                                                             sel_dev)
+                slots = np.flatnonzero(todo)
+                nxt, finite_dev = self._sample(logits, slots, slots, rng)
+            with log.phase("engine.decode.sync"):
+                # host-sync: the ONE batched device->host sync of the
+                # common (all-hit) tiered tick — sampled tokens, the
+                # nan-guard mask and the winner mask cross together
+                nxt_np, finite, win_np = jax.device_get(
+                    (nxt, finite_dev, win))
+            with log.phase("engine.decode.commit"):
+                miss, winners = self._commit_tiered(
+                    attempt, pt, win_np, todo, done, nxt_np, finite,
+                    nxt_out, fin_out)
+            with log.phase("engine.decode.prep"):
+                self._prep_rerun(miss, winners, todo, ran & ~done, snap)
             if not todo.any():
                 return nxt_out, fin_out, done
         raise RuntimeError(
             f"tiered decode did not converge in {_TIERED_MAX_RUNS} runs "
             "(promotion/selection ping-pong; raise device_pages)")
+
+    def _commit_tiered(self, attempt, pt, win_np, todo, done, nxt_np,
+                       finite, nxt_out, fin_out):
+        """Commit every slot of one tiered run whose attended pages were
+        all resident (in place on ``todo``/``done``/``nxt_out``/
+        ``fin_out``); returns (misses, winners) of the rest."""
+        winners = self._winner_pages(pt, np.asarray(win_np), todo)
+        miss = {s: [p for p in sorted(pages)
+                    if self.pool.tier_of(p) != PC.RESIDENT]
+                for s, pages in winners.items()}
+        miss = {s: ps_ for s, ps_ in miss.items() if ps_}
+        if attempt == 0:
+            uniq = set().union(*winners.values()) if winners else set()
+            n_miss = sum(self.pool.tier_of(p) != PC.RESIDENT
+                         for p in uniq)
+            self.n_prefetch_misses += n_miss
+            self.n_prefetch_hits += len(uniq) - n_miss
+        # commit every fully-resident slot: its token is exact, its
+        # K/V row write is input-only (valid even beside garbage
+        # reads), and its advanced state must NOT be restored
+        for s in winners:
+            if s in miss:
+                continue
+            done[s] = True
+            todo[s] = False
+            nxt_out[s] = nxt_np[s]
+            if fin_out is not None:
+                fin_out[s] = bool(finite[s])
+            for p in winners[s]:
+                self._page_last_use[p] = self.ticks
+        return miss, winners
+
+    def _prep_rerun(self, miss, winners, todo, stale, snap) -> None:
+        """Before a tiered re-run: promote the misses of the slots still
+        to do, and rewind the recurrent state of every slot that ran
+        without committing."""
+        if todo.any():
+            self.n_decode_reruns += 1
+            self._repair_misses(miss, winners, todo)
+            self._fetch.drain()
+            self._prune_host()
+        # restore every slot that ran this attempt without committing —
+        # both the re-running and the just-deferred: their recurrent
+        # state advanced on garbage attention inputs and must rewind to
+        # the snapshot (committed slots keep theirs, so each stream's
+        # state advances exactly once)
+        if snap is not None and stale.any():
+            # one jitted masked restore over every stale slot at once
+            # (was a per-slot snapshot/reset Python loop: a chain of
+            # eagerly-dispatched slice updates per re-run)
+            layers = self.cache["layers"]
+            sub = {k: layers[k] for k in snap}
+            sub = self._rewind(sub, snap, jnp.asarray(stale))
+            self.cache = {"layers": {**layers, **sub}}
 
     def _run_decode_t(self, pt, ft, sel_dev):
         """Tiered twin of ``_run_decode`` (frame-table decode program)."""
@@ -1376,7 +1420,9 @@ class PagedServingEngine:
                     if self.slot_req[s] is None]
             if not free:
                 break
-            self._admit_into(free[0], self._pop_next())
+            req = self._pop_next()
+            with self._admit_span(req):
+                self._admit_into(free[0], req)
         if not self.policy.preempt_for_admission:
             return
         while self._queue:
@@ -1388,11 +1434,19 @@ class PagedServingEngine:
             if not worse:
                 return
             del self._queue[qi]
-            self._preempt(max(worse,
-                              key=lambda s: self._key(self.slot_req[s])))
-            slot = next(s for s in range(self.n_slots)
-                        if self.slot_req[s] is None)
-            self._admit_into(slot, cand)
+            with self._admit_span(cand):
+                self._preempt(max(
+                    worse, key=lambda s: self._key(self.slot_req[s])))
+                slot = next(s for s in range(self.n_slots)
+                            if self.slot_req[s] is None)
+                self._admit_into(slot, cand)
+
+    def _admit_span(self, req: Request):
+        """The ``engine.admit`` phase of one request, with how long it
+        has waited since ``submit`` on the engine's clock."""
+        return self.tick_log.phase(
+            "engine.admit", rid=req.rid,
+            queued_ms=int(1e3 * (self._clock() - req.t_submit)))
 
     def _prefill_phase(self) -> None:
         """Advance mid-prefill slots, most urgent first, spending at most
@@ -1404,7 +1458,13 @@ class PagedServingEngine:
                        key=lambda s: self._key(self.slot_req[s]))
         for slot in slots:
             while budget > 0 and slot in self._prefill_at:
-                n = self._prefill_slot_chunk(slot)
+                req = self.slot_req[slot]
+                with self.tick_log.phase(
+                        "engine.prefill", rid=req.rid,
+                        tokens=min(self.prefill_chunk,
+                                   len(req.prompt) - 1
+                                   - self._prefill_at[slot])):
+                    n = self._prefill_slot_chunk(slot)
                 if n < 0:
                     break              # this slot is pool-contended; a
                 budget -= max(n, 1)    # later slot may still fit (e.g. a
@@ -1466,6 +1526,30 @@ class PagedServingEngine:
     def _decode_phase(self, rng: Optional[jax.Array]) -> bool:
         if not self.live.any():
             return False
+        log = self.tick_log
+        with log.phase("engine.decode.prep"):
+            sel = self._prep_decode()
+        if not sel.any():
+            return False
+        # the batched step writes a token for *every* slot; unselected
+        # slots (idle, mid-prefill, live-but-over-budget) must land in the
+        # trash page, not at their current position — and their StateSlot
+        # components must not advance (``live`` mask)
+        if self.tiered:
+            nxt_np, finite, sel = self._decode_tiered(sel, rng)
+            if not sel.any():
+                return False    # every stream deferred to the next tick
+            log.count(decode_rows=int(sel.sum()))
+        else:
+            nxt_np, finite = self._decode_batch(sel, rng)
+        with log.phase("engine.decode.commit"):
+            self._commit_decode(sel, nxt_np, finite)
+        return True
+
+    def _prep_decode(self) -> np.ndarray:
+        """Pick this tick's decode batch and make every chosen slot's
+        target page exist and be privately writable. Returns the selected
+        slot mask (a slot may drop out: preempted or deferred)."""
         # decode-budget selection: when more slots are live than the
         # budget covers, the policy's decode key picks this tick's batch
         # (strict priority classes, round-robin inside a class)
@@ -1523,18 +1607,29 @@ class PagedServingEngine:
                     self._repin_tail(slot)
                     self._page_last_use[held[-1]] = self.ticks
                 prepped.add(slot)
-        sel &= self.live
-        if not sel.any():
-            return False
-        # the batched step writes a token for *every* slot; unselected
-        # slots (idle, mid-prefill, live-but-over-budget) must land in the
-        # trash page, not at their current position — and their StateSlot
-        # components must not advance (``live`` mask)
-        if self.tiered:
-            nxt_np, finite, sel = self._decode_tiered(sel, rng)
-            if not sel.any():
-                return False    # every stream deferred to the next tick
-        else:
+        return sel & self.live
+
+    def _sample(self, logits, rows, slots, rng):
+        """Sampled tokens and the nan-guard mask of one decode run, still
+        on the device. ``rows`` are the logits rows of the live ``slots``
+        (the ``nan_logits`` fault site poisons a row by its slot)."""
+        if self._faults is not None:
+            bad = [r for r, s in zip(rows, slots)
+                   if self._faults.hit("nan_logits", int(s))]
+            if bad:
+                logits = logits.at[jnp.asarray(bad, jnp.int32)].set(jnp.nan)
+        finite_dev = jnp.isfinite(logits).all(axis=-1) \
+            if self.nan_guard else None
+        nxt = sample_next(logits, greedy=self.greedy, rng=rng,
+                          ticks=self.ticks)
+        return nxt, finite_dev
+
+    def _decode_batch(self, sel: np.ndarray, rng):
+        """One single-tier decode step over the selected slots: packed
+        when a warmed bucket narrower than ``n_slots`` fits, else masked
+        full-width. Returns (tokens, finite) over the full slot axis."""
+        log = self.tick_log
+        with log.phase("engine.decode.prep"):
             order = self._packed_order(sel)
             if order is not None:
                 # gather-packed step: the batch is the live slots plus
@@ -1546,59 +1641,47 @@ class PagedServingEngine:
                 n_live = int(plive.sum())
                 self.n_packed_ticks += 1
                 self.n_packed_rows_saved += self.n_slots - len(sidx)
-                keep = plive.astype(np.int32)
-                if self.n_groups > 1:
-                    pt = np.stack([t[sidx] for t in self._group_tables()],
-                                  axis=1) * keep[:, None, None]
-                else:
-                    pt = self.page_table[sidx] * keep[:, None]
-                logits, self.cache = self._run_decode_packed(
-                    len(sidx), sidx, pt, plive)
-                if self._faults is not None:
-                    bad = [i for i in range(n_live)
-                           if self._faults.hit("nan_logits",
-                                               int(sidx[i]))]
-                    if bad:
-                        logits = logits.at[
-                            jnp.asarray(bad, jnp.int32)].set(jnp.nan)
-                finite_dev = jnp.isfinite(logits).all(axis=-1) \
-                    if self.nan_guard else None
-                nxt = sample_next(logits, greedy=self.greedy, rng=rng,
-                                  ticks=self.ticks)
-                # host-sync: the ONE batched device->host sync of the
-                # packed decode tick
-                nxt_p, fin_p = jax.device_get((nxt, finite_dev))
-                nxt_np = np.zeros((self.n_slots,), nxt_p.dtype)
-                nxt_np[sidx[:n_live]] = nxt_p[:n_live]
-                finite = None
-                if fin_p is not None:
-                    finite = np.ones((self.n_slots,), bool)
-                    finite[sidx[:n_live]] = fin_p[:n_live]
+                keep, batch = plive.astype(np.int32), sidx
+                rows, slots = range(n_live), sidx[:n_live]
+                log.count(decode_rows=n_live, packed_bucket=len(sidx))
             else:
                 self.n_masked_ticks += 1
                 sel_dev = jnp.asarray(sel)
-                keep = sel.astype(np.int32)
-                if self.n_groups > 1:
-                    pt = np.stack(self._group_tables(),
-                                  axis=1) * keep[:, None, None]
-                else:
-                    pt = self.page_table * keep[:, None]
+                keep, batch = sel.astype(np.int32), slice(None)
+                rows = slots = np.flatnonzero(sel)
+                log.count(decode_rows=len(rows))
+            tables = [t[batch] for t in self._group_tables()]
+            pt = (np.stack(tables, axis=1) * keep[:, None, None]
+                  if self.n_groups > 1 else tables[0] * keep[:, None])
+        with log.phase("engine.decode.launch"):
+            if order is not None:
+                logits, self.cache = self._run_decode_packed(
+                    len(sidx), sidx, pt, plive)
+            else:
                 logits, self.cache = self._run_decode(pt, sel_dev)
-                if self._faults is not None:
-                    bad = [s for s in np.flatnonzero(sel)
-                           if self._faults.hit("nan_logits", int(s))]
-                    if bad:
-                        logits = logits.at[
-                            jnp.asarray(bad, jnp.int32)].set(jnp.nan)
-                finite_dev = jnp.isfinite(logits).all(axis=-1) \
-                    if self.nan_guard else None
-                nxt = sample_next(logits, greedy=self.greedy, rng=rng,
-                                  ticks=self.ticks)
-                # host-sync: the ONE batched device->host sync of the
-                # decode tick — sampled tokens (and the nan-guard mask)
-                # must reach Python to drive per-request lifecycle;
-                # everything else stays host-side
-                nxt_np, finite = jax.device_get((nxt, finite_dev))
+            nxt, finite_dev = self._sample(logits, rows, slots, rng)
+        with log.phase("engine.decode.sync"):
+            # host-sync: the ONE batched device->host sync of the decode
+            # tick — sampled tokens (and the nan-guard mask) must reach
+            # Python to drive per-request lifecycle; everything else
+            # stays host-side
+            nxt_np, finite = jax.device_get((nxt, finite_dev))
+        if order is None:
+            return nxt_np, finite
+        with log.phase("engine.decode.commit"):
+            # unpack the packed rows to their slots
+            full = np.zeros((self.n_slots,), nxt_np.dtype)
+            full[slots] = nxt_np[:n_live]
+            fin = None
+            if finite is not None:
+                fin = np.ones((self.n_slots,), bool)
+                fin[slots] = finite[:n_live]
+        return full, fin
+
+    def _commit_decode(self, sel: np.ndarray, nxt_np: np.ndarray,
+                       finite: Optional[np.ndarray]) -> None:
+        """Advance the decoded slots: append each sampled token, stamp
+        first-token times, finish or quarantine requests."""
         self.pos += sel.astype(np.int32)
         self._last_decoded[sel] = self.ticks
         for slot in range(self.n_slots):
@@ -1626,7 +1709,6 @@ class PagedServingEngine:
                 self._release_slot(slot)
             else:
                 self.last_tok[slot] = tok
-        return True
 
     def _degrade(self, run):
         """Run one decode program through the degradation ladder. Only
@@ -1721,16 +1803,26 @@ class PagedServingEngine:
     # ------------------------------------------------------------- tick
 
     def tick(self, rng: Optional[jax.Array] = None) -> None:
-        if self._faults is not None:
-            self._faults.advance(self.ticks)
-        self._expire_deadlines()
-        self._admission_phase()
-        self._prefill_phase()
-        self._decode_phase(rng)
-        self._inject_corruption()
-        self.ticks += 1
-        if self.audit:
-            FI.audit_engine(self)
+        log = self.tick_log
+        before = (self.n_prefill_computed_tokens, self.n_pages_grown,
+                  self.n_preempted)
+        with log.tick(self.ticks):
+            with log.phase("engine.expire"):
+                if self._faults is not None:
+                    self._faults.advance(self.ticks)
+                self._expire_deadlines()
+            self._admission_phase()
+            self._prefill_phase()
+            self._decode_phase(rng)
+            self._inject_corruption()
+            self.ticks += 1
+            log.count(
+                prefill_tokens=self.n_prefill_computed_tokens - before[0],
+                pages_grown=self.n_pages_grown - before[1],
+                preemptions=self.n_preempted - before[2])
+            if self.audit:
+                with log.phase("engine.audit"):
+                    FI.audit_engine(self)
 
     @property
     def n_prefix_hit_tokens(self) -> int:
@@ -1796,6 +1888,7 @@ class PagedServingEngine:
             "n_state_restores": self.n_state_restores,
             "peak_slot_pages": self.peak_slot_pages,
             "n_prefill_computed_tokens": self.n_prefill_computed_tokens,
+            "n_pages_grown": self.n_pages_grown,
             "prefix_hit_rate": self.prefix_hit_rate(),
             "lifecycle": dict(self.lifecycle_counts),
             "n_stalled": self.n_stalled,
@@ -1810,6 +1903,8 @@ class PagedServingEngine:
                 "n_rows_saved": self.n_packed_rows_saved,
                 "n_sealed_fallbacks": self.n_packed_fallbacks,
             },
+            # per phase, mean / p95 / max ms per tick over the tick log
+            "tick_phases_ms": self.tick_log.summary(),
         }
         if self.n_groups > 1:
             out["table_groups"] = {
