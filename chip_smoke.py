@@ -117,11 +117,13 @@ def loki_probe(follow: str, records: list):
 
     def probe(q, k, v, cur_len, proj, cfg, *, sliding_window=0,
               logit_scale=None, page_table=None, page_size=0, k_scale=None,
-              v_scale=None, interpret=None):
+              v_scale=None, layer=None, interpret=None):
         kw = dict(sliding_window=sliding_window, logit_scale=logit_scale,
                   page_table=page_table, page_size=page_size,
                   k_scale=k_scale, v_scale=v_scale)
-        got = pallas(q, k, v, cur_len, proj, cfg, **kw)
+        got = pallas(q, k, v, cur_len, proj, cfg, layer=layer, **kw)
+        # the oracle and the select kernel read the layer's own pools
+        k, v = layout.layer_pool(k, layer), layout.layer_pool(v, layer)
         b, dim = q.shape[0], q.shape[-1]
         kd = layout.pool_width(k)
         q_hat = loki.grouped_queries(q, proj, kd)    # the kernel's queries

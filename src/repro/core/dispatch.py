@@ -149,7 +149,7 @@ def _token_fallback(q_rope, k_hat_cache, v_cache, cur_len, proj, cfg,
 def loki_block_decode(q_rope, k_hat_cache, v_cache, cur_len, proj,
                       cfg: LokiConfig, *, sliding_window: int = 0,
                       logit_scale=None, page_table=None, page_size: int = 0,
-                      k_scale=None, v_scale=None,
+                      k_scale=None, v_scale=None, layer=None,
                       interpret: Optional[bool] = None):
     """Block-granular Loki decode through the configured backend.
 
@@ -164,9 +164,17 @@ def loki_block_decode(q_rope, k_hat_cache, v_cache, cur_len, proj,
     (Hkv,·,R): the Pallas kernels index their block DMAs through the table,
     the jnp paths gather the logical view through the same table. Quantized
     layouts pass the pools' per-page f32 ``k_scale``/``v_scale`` sidecars;
-    every path dequantizes behind its DMA/gather, never in HBM."""
+    every path dequantizes behind its DMA/gather, never in HBM.
+
+    ``layer`` (traced int32): the pools are every layer's, stacked
+    (L,Hkv,·,R), and this layer is read. The fused kernel addresses it
+    inside the stack; every other route reads the layer's own pools
+    (``layout.layer_pool``), which only the route taken keeps."""
     backend = resolve_backend(cfg.backend)
     paged = page_table is not None
+    k_stack, v_stack = k_hat_cache, v_cache
+    k_hat_cache = layout.layer_pool(k_hat_cache, layer)
+    v_cache = layout.layer_pool(v_cache, layer)
     b, h = q_rope.shape[0], q_rope.shape[1]
     smax, n_kv, kd, dim = layout.cache_dims(k_hat_cache, v_cache,
                                             page_table, page_size)
@@ -230,12 +238,16 @@ def loki_block_decode(q_rope, k_hat_cache, v_cache, cur_len, proj,
                        -(-sliding_window // plan.block_size) + 1)
     q_hat = loki.grouped_queries(q_rope, proj, kd)
     cur = jnp.broadcast_to(jnp.asarray(cur_len, jnp.int32), (b,))
-    fn = (ops.loki_decode_fused if plan.variant == "fused"
-          else ops.loki_decode_two_kernel)
-    out = fn(q_hat, k_hat_cache, v_cache, cur, d=d, k_blocks=k_blocks,
-             block_size=plan.block_size, scale=logit_scale,
-             local_window=cfg.local_window, sliding_window=sliding_window,
-             interpret=interpret, **pargs, **qargs)
+    kargs = dict(d=d, k_blocks=k_blocks, block_size=plan.block_size,
+                 scale=logit_scale, local_window=cfg.local_window,
+                 sliding_window=sliding_window, interpret=interpret,
+                 **pargs, **qargs)
+    if plan.variant == "fused":
+        out = ops.loki_decode_fused(q_hat, k_stack, v_stack, cur,
+                                    layer=layer, **kargs)
+    else:
+        out = ops.loki_decode_two_kernel(q_hat, k_hat_cache, v_cache, cur,
+                                         **kargs)
     return out.reshape(b, h, dim)
 
 

@@ -149,19 +149,20 @@ def _score_and_select(ln, q_hat, kd_src, kd_buf, sem_kd, write_sel=None,
     return sel
 
 
-def _fused_kernel(*args, paged: bool, quant: bool, ps: int, d: int, bs: int,
-                  nb: int, nb_pad: int, k_blocks: int, scale: float, g: int,
-                  kdim: int, dim: int, local_window: int,
-                  sliding_window: int):
+def _fused_kernel(*args, paged: bool, stacked: bool, quant: bool, ps: int,
+                  d: int, bs: int, nb: int, nb_pad: int, k_blocks: int,
+                  scale: float, g: int, kdim: int, dim: int,
+                  local_window: int, sliding_window: int):
+    # scalar prefetch: cur_len [, page_table [, layer]]
+    n_pre = 1 + paged + stacked
+    len_ref, pt_ref = args[0], args[1] if paged else None
+    lyr = args[2][0] if stacked else None
     if quant:
-        (len_ref, pt_ref, q_ref, k_ref, v_ref, ksc_ref, vsc_ref, out_ref,
-         kd_buf, kbuf, vbuf, sel, sem_kd, sem_kv) = args
-    elif paged:
-        (len_ref, pt_ref, q_ref, k_ref, v_ref, out_ref,
-         kd_buf, kbuf, vbuf, sel, sem_kd, sem_kv) = args
+        (q_ref, k_ref, v_ref, ksc_ref, vsc_ref, out_ref,
+         kd_buf, kbuf, vbuf, sel, sem_kd, sem_kv) = args[n_pre:]
     else:
-        (len_ref, q_ref, k_ref, v_ref, out_ref,
-         kd_buf, kbuf, vbuf, sel, sem_kd, sem_kv) = args
+        (q_ref, k_ref, v_ref, out_ref,
+         kd_buf, kbuf, vbuf, sel, sem_kd, sem_kv) = args[n_pre:]
     b = pl.program_id(0)
     h = pl.program_id(1)
     ln = len_ref[b]
@@ -170,11 +171,13 @@ def _fused_kernel(*args, paged: bool, quant: bool, ps: int, d: int, bs: int,
     def k_slice(ref, blk, width):
         """HBM source for (logical) block ``blk``: direct for contiguous
         caches, through the page table for pooled ones (the paged
-        index-indirection — blocks tile pages exactly)."""
+        index-indirection — blocks tile pages exactly), in layer ``lyr``
+        of stacked pools."""
         tok = blk * bs
         if paged:
             row = pt_ref[b, tok // ps] * ps + tok % ps
-            return ref.at[h, pl.ds(0, width), pl.ds(row, bs)]
+            idx = (h, pl.ds(0, width), pl.ds(row, bs))
+            return ref.at[(lyr,) + idx if stacked else idx]
         return ref.at[b, pl.ds(tok, bs), h, pl.ds(0, width)]
 
     def page_of(blk):
@@ -243,36 +246,47 @@ def _fused_kernel(*args, paged: bool, quant: bool, ps: int, d: int, bs: int,
         out_ref.dtype)
 
 
-def _paged_args(q_hat, k_hat, cur_len, page_table, page_size, block_size):
-    """Validate/resolve the (paged?, logical length) of a kernel call."""
+def _paged_args(q_hat, k_hat, cur_len, page_table, page_size, block_size,
+                layer=None):
+    """Validate/resolve the (paged?, logical length) of a kernel call.
+    ``layer``: the pools are stacked (L, Hkv, W, R) and the kernel reads
+    this layer of them (one more scalar-prefetch operand)."""
     paged = page_table is not None
     if paged:
         assert page_size > 0 and page_size % block_size == 0, \
             "kernel blocks must tile pages exactly (page_size % bs == 0)"
-        assert k_hat.ndim == 3, "paged caches are pooled (Hkv, W, R)"
+        assert k_hat.ndim == 3 + (layer is not None), \
+            "paged caches are pooled (Hkv, W, R), stacked (L, Hkv, W, R) " \
+            "with a layer"
         s_len = page_table.shape[1] * page_size
         prefetch = (cur_len.astype(jnp.int32),
                     page_table.astype(jnp.int32))
+        if layer is not None:
+            prefetch += (jnp.reshape(layer, (1,)).astype(jnp.int32),)
     else:
+        assert layer is None, "only paged pools stack layers"
         s_len = k_hat.shape[1]
         prefetch = (cur_len.astype(jnp.int32),)
     return paged, s_len, prefetch
 
 
-@kernel_entry(scalar_prefetch=("cur_len", "page_table"),
+@kernel_entry(scalar_prefetch=("cur_len", "page_table", "layer"),
               smem_sidecars=("k_scale", "v_scale"),
               paged_operand="page_table", grid="(B, Hkv)")
 def fused_loki_decode(q_hat, k_hat, v, cur_len, *, d: int, k_blocks: int,
                       block_size: int = 128, scale=None,
                       local_window: int = 0, sliding_window: int = 0,
                       page_table=None, page_size: int = 0,
-                      k_scale=None, v_scale=None,
+                      k_scale=None, v_scale=None, layer=None,
                       interpret: bool = False):
     """Single-pass Loki decode. (B,Hkv,G,W),(B,S,Hkv,W),(B,S,Hkv,D),(B,)
     -> (B,Hkv,G,D). Requires cur_len >= 1 per row (the decode invariant:
     the new token is already in the cache). With ``page_table``/``page_size``
     the caches are pooled (Hkv,W,R) and block DMAs resolve through the
-    table. ``W <= D`` is the stored latent key width (rank-r PageLayout);
+    table; with ``layer`` too (a traced int32 scalar) they are the stacked
+    (L,Hkv,W,R) pools of every layer and each DMA reads that layer, so no
+    layer is ever sliced out of the stack. ``W <= D`` is the stored latent
+    key width (rank-r PageLayout);
     queries arrive already projected/truncated to W, values stay full D.
     Quantized layouts pass ``k_scale``/``v_scale`` (n_pages,) f32 per-page
     scales (paged only); the kernel multiplies them in right after each
@@ -280,7 +294,7 @@ def fused_loki_decode(q_hat, k_hat, v, cur_len, *, d: int, k_blocks: int,
     b, n_kv, g, kdim = q_hat.shape
     bs = block_size
     paged, s_len, prefetch = _paged_args(q_hat, k_hat, cur_len, page_table,
-                                         page_size, bs)
+                                         page_size, bs, layer)
     dim = cache_width(v, paged)
     assert cache_width(k_hat, paged) == kdim, \
         "q_hat/k_hat latent widths must match"
@@ -294,14 +308,11 @@ def fused_loki_decode(q_hat, k_hat, v, cur_len, *, d: int, k_blocks: int,
     scale = float(scale if scale is not None else dim ** -0.5)
 
     kernel = functools.partial(
-        _fused_kernel, paged=paged, quant=quant, ps=page_size, d=d, bs=bs,
-        nb=nb, nb_pad=nb_pad, k_blocks=k_blocks, scale=scale, g=g,
-        kdim=kdim, dim=dim, local_window=local_window,
+        _fused_kernel, paged=paged, stacked=layer is not None, quant=quant,
+        ps=page_size, d=d, bs=bs, nb=nb, nb_pad=nb_pad, k_blocks=k_blocks,
+        scale=scale, g=g, kdim=kdim, dim=dim, local_window=local_window,
         sliding_window=sliding_window)
-    if paged:
-        io_map = lambda i, j, ln, pt: (i, j, 0, 0)
-    else:
-        io_map = lambda i, j, ln: (i, j, 0, 0)
+    io_map = lambda i, j, *prefetched: (i, j, 0, 0)
     in_specs = [
         pl.BlockSpec((1, 1, g, kdim), io_map),
         # the caches stay in HBM; the kernel DMAs d-slices and the
@@ -378,9 +389,10 @@ def fused_exact_topk_decode(q_hat, k_hat, v, cur_len, *, k_blocks: int,
 
     # d = kdim: the "approximate" stream IS the exact score pass
     kernel = functools.partial(
-        _fused_kernel, paged=paged, quant=quant, ps=page_size, d=kdim,
-        bs=bs, nb=nb, nb_pad=nb_pad, k_blocks=k_blocks, scale=scale, g=g,
-        kdim=kdim, dim=dim, local_window=0, sliding_window=sliding_window)
+        _fused_kernel, paged=paged, stacked=False, quant=quant,
+        ps=page_size, d=kdim, bs=bs, nb=nb, nb_pad=nb_pad,
+        k_blocks=k_blocks, scale=scale, g=g, kdim=kdim, dim=dim,
+        local_window=0, sliding_window=sliding_window)
     if paged:
         io_map = lambda i, j, ln, pt: (i, j, 0, 0)
     else:

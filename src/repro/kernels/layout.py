@@ -36,6 +36,14 @@ def pool_width(pool) -> int:
     return pool.shape[-2]
 
 
+def layer_pool(pool, layer):
+    """Layer ``layer`` (traced) of stacked pools, or ``pool`` itself where
+    ``layer`` is None. Routes that cannot address the stack read this."""
+    if layer is None:
+        return pool
+    return jax.lax.dynamic_index_in_dim(pool, layer, 0, keepdims=False)
+
+
 def page_slice(pool, start, n: int):
     """Rows ``[start, start + n)`` of every head and feature: one page's
     tiles when ``start`` is a page boundary. ``start`` may be traced."""
@@ -60,10 +68,11 @@ def cache_width(cache, paged: bool) -> int:
 
 
 def cache_dims(k_cache, v_cache, page_table, page_size: int):
-    """(smax, n_kv, kd, dim) of contiguous caches or of pools read through
-    ``page_table``: logical length, KV heads, key and value widths."""
+    """(smax, n_kv, kd, dim) of contiguous caches or of pools (one layer's
+    or stacked) read through ``page_table``: logical length, KV heads, key
+    and value widths."""
     if page_table is not None:
-        return (page_table.shape[1] * page_size, k_cache.shape[0],
+        return (page_table.shape[1] * page_size, k_cache.shape[-3],
                 pool_width(k_cache), pool_width(v_cache))
     _, smax, n_kv, kd = k_cache.shape
     return smax, n_kv, kd, v_cache.shape[-1]

@@ -89,22 +89,23 @@ def loki_decode_fused(q_hat, k_hat, v, cur_len, *, d: int, k_blocks: int,
                       block_size: int = 128, scale=None,
                       local_window: int = 0, sliding_window: int = 0,
                       page_table=None, page_size: int = 0,
-                      k_scale=None, v_scale=None,
+                      k_scale=None, v_scale=None, layer=None,
                       interpret: bool = False):
     """Single-pass fused decode (DESIGN.md §4): score, select and attend in
     one kernel; no score/selection tensor ever reaches HBM.
 
     q_hat (B,Hkv,G,W) grouped PCA-basis queries (W = stored latent K width,
     <= D); k_hat (B,S,Hkv,W) / v (B,S,Hkv,D) model-native caches (or pooled
-    (Hkv,·,R) with ``page_table``); cur_len (B,). Quantized PageLayouts pass
-    the pools' (n_pages,) f32 ``k_scale``/``v_scale`` sidecars (paged only).
-    Returns (B,Hkv,G,D)."""
+    (Hkv,·,R) with ``page_table``, or every layer's pools stacked
+    (L,Hkv,·,R) with ``page_table`` and ``layer``); cur_len (B,). Quantized
+    PageLayouts pass the pools' (n_pages,) f32 ``k_scale``/``v_scale``
+    sidecars (paged only). Returns (B,Hkv,G,D)."""
     return fused_loki_decode(q_hat, k_hat, v, cur_len, d=d,
                              k_blocks=k_blocks, block_size=block_size,
                              scale=scale, local_window=local_window,
                              sliding_window=sliding_window,
                              page_table=page_table, page_size=page_size,
-                             k_scale=k_scale, v_scale=v_scale,
+                             k_scale=k_scale, v_scale=v_scale, layer=layer,
                              interpret=interpret)
 
 

@@ -37,6 +37,7 @@ KERNEL_MODULES = (
     "repro.kernels.approx_scores",
     "repro.kernels.approx_scores_fm",
     "repro.kernels.flash_attention",
+    "repro.kernels.pool_write",
 )
 
 

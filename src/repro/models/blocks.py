@@ -127,11 +127,11 @@ _UINT_OF = {2: jnp.uint16, 4: jnp.uint32, 1: jnp.uint8}
 def _write_cache(cache_arr, new, pos_len):
     """Insert new (B,Hkv,D) rows at per-slot positions pos_len (B,).
 
-    The vmapped DUS lowers to a scatter. Backends without a native
-    low-precision scatter (XLA:CPU legalizes bf16 scatter via f32) would
-    otherwise rewrite the whole buffer with converts every step (§Perf L3),
-    so we scatter the raw bit pattern as an unsigned int — a free bitcast on
-    TPU, and in-place everywhere."""
+    The vmapped DUS lowers to a scatter. XLA:CPU legalizes a bf16 scatter
+    through f32 and would rewrite the whole buffer with converts every
+    step (§Perf L3), so the raw bit pattern is scattered as an unsigned
+    int. (On TPU such a bitcast of a whole cache is a copy; this
+    contiguous cache serves the dense engine, the CPU reference.)"""
     b = new.shape[0]
     dt = cache_arr.dtype
     uint = _UINT_OF.get(jnp.dtype(dt).itemsize) if jnp.issubdtype(
@@ -151,7 +151,7 @@ def _write_cache(cache_arr, new, pos_len):
 
 def attn_decode(p, cache, x, pos_len, cfg: ModelConfig, *,
                 page_table=None, page_size: int = 0, frame_table=None,
-                rank=None, sliding_window=None):
+                rank=None, sliding_window=None, layer=None):
     """One-token decode with the configured attention policy.
 
     x (B,E); pos_len (B,) tokens already cached. Returns (y (B,E), cache).
@@ -179,7 +179,14 @@ def attn_decode(p, cache, x, pos_len, cfg: ModelConfig, *,
     ``rank`` (traced scalar): this layer's latent-K rank under per-layer
     ``cfg.page_ranks`` — tail columns of the stored keys are zero-masked,
     which is self-consistent truncation (zeroed dims contribute nothing
-    to q̂·k̂)."""
+    to q̂·k̂).
+
+    ``layer`` (traced int32; one-tier float pools only): ``cache`` holds
+    every layer's pools stacked (L,Hkv,·,R) and this layer is decoded in
+    place in the stack: the new rows go in through
+    ``PC.write_token_rows``'s row-write kernel and the Loki kernel reads
+    the layer inside the stack, so no pool is copied or sliced on the
+    Pallas route. The cache returned is the whole stack."""
     hd = cfg.resolved_head_dim
     b = x.shape[0]
     q, k, v = _qkv(p, x[:, None, :], cfg)
@@ -266,19 +273,24 @@ def attn_decode(p, cache, x, pos_len, cfg: ModelConfig, *,
                 positions, page_size, qmax=lay.qmax)
             cache = {"k": kp, "v": vp, "k_scale": ks, "v_scale": vs}
         else:
+            wargs = dict(layer=layer, backend=cfg.loki.backend)
             cache = {"k": PC.write_token_rows(cache["k"], k_store,
                                               page_table, positions,
-                                              page_size),
+                                              page_size, **wargs),
                      "v": PC.write_token_rows(cache["v"], v, page_table,
-                                              positions, page_size)}
+                                              positions, page_size,
+                                              **wargs)}
+        # this layer's pools, for the routes that cannot address the stack
+        read = {n: layout.layer_pool(a, layer) for n, a in cache.items()}
 
         def view(name):
-            return PC.gather_logical_dq(cache[name],
+            return PC.gather_logical_dq(read[name],
                                         cache.get(name + "_scale"),
                                         page_table, page_size)
     else:
         cache = {"k": _write_cache(cache["k"], k_store, pos_len),
                  "v": _write_cache(cache["v"], v, pos_len)}
+        read = cache
 
         def view(name):
             return cache[name]
@@ -296,7 +308,7 @@ def attn_decode(p, cache, x, pos_len, cfg: ModelConfig, *,
         # backend-dispatched like loki_block: on the Pallas path the paged
         # streaming kernel reads live blocks through the table; the XLA
         # path is the bit-preserved gather + decode_full reference
-        out = dispatch.full_paged_decode(q_read, cache["k"], cache["v"],
+        out = dispatch.full_paged_decode(q_read, read["k"], read["v"],
                                          cur_len, backend=cfg.loki.backend,
                                          block_size=cfg.loki.block_size,
                                          sliding_window=sw,
@@ -308,8 +320,8 @@ def attn_decode(p, cache, x, pos_len, cfg: ModelConfig, *,
     elif policy == "exact_topk":
         # exact scores + block top-k fused the same way loki_block's
         # approximate pass is; XLA keeps the token-granular reference
-        out = dispatch.exact_topk_paged_decode(q_read, cache["k"],
-                                               cache["v"], cur_len,
+        out = dispatch.exact_topk_paged_decode(q_read, read["k"],
+                                               read["v"], cur_len,
                                                cfg.loki,
                                                logit_scale=hd ** -0.5,
                                                page_table=page_table,
@@ -336,7 +348,8 @@ def attn_decode(p, cache, x, pos_len, cfg: ModelConfig, *,
                                          page_table=page_table,
                                          page_size=page_size,
                                          k_scale=cache.get("k_scale"),
-                                         v_scale=cache.get("v_scale"))
+                                         v_scale=cache.get("v_scale"),
+                                         layer=layer)
     elif policy == "pcaattn":
         out = baselines.pcaattn_decode(q, view("k"), view("v"),
                                        cur_len, proj, cfg.loki)

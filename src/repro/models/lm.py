@@ -390,9 +390,24 @@ def init_paged_cache(cfg: ModelConfig, n_pages: int, page_size: int,
 
 # --------------------------------------------------------------- decode
 
+@jax.named_scope("pool")
+def _at_layer(tree, layer):
+    return jax.tree.map(lambda a: layout.layer_pool(a, layer), tree)
+
+
+@jax.named_scope("pool")
+def _put_layer(tree, one, layer):
+    return jax.tree.map(
+        lambda a, o: jax.lax.dynamic_update_index_in_dim(
+            a, o.astype(a.dtype), layer, 0), tree, one)
+
+
 def _layer_decode(p, c, x, pos_len, cfg: ModelConfig, kind: str, *,
                   page_table=None, page_size: int = 0, live=None,
-                  frame_table=None, rank=None, sliding_window=None):
+                  frame_table=None, rank=None, sliding_window=None,
+                  layer=None):
+    """One layer's decode; ``layer`` (traced int32) hands ``c["attn"]`` as
+    every layer's stacked pools, decoded in place (``B.attn_decode``)."""
     def keep_live(new, old):
         """StateSlot protection for the batched paged tick: slots that are
         idle or mid-prefill must not have their carried recurrent state
@@ -421,7 +436,8 @@ def _layer_decode(p, c, x, pos_len, cfg: ModelConfig, kind: str, *,
                                             pos_len, cfg,
                                             page_table=page_table,
                                             page_size=page_size, rank=rank,
-                                            sliding_window=sliding_window)
+                                            sliding_window=sliding_window,
+                                            layer=layer)
             c = dict(c)
             c["attn"] = new_attn
             if kind == "hybrid":
@@ -461,8 +477,6 @@ def _layer_decode(p, c, x, pos_len, cfg: ModelConfig, kind: str, *,
             x = x + L.mlp_apply(p["mlp"], h, cfg)
     return x, c, win
 
-
-_UINT_OF = {1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32}
 
 # cache keys whose leading (post-L) axis is the *slot* axis — everything
 # else in a paged cache is pooled (no batch dim) and shared by all slots
@@ -514,28 +528,6 @@ def _slot_scatter(full_layers, packed_layers, sidx, scan: bool):
     return [merge(f, p, 0) for f, p in zip(full_layers, packed_layers)]
 
 
-@jax.named_scope("pool")
-def _cache_bits(tree):
-    """Float leaves -> same-width uint views (free bitcast on TPU). The scan
-    then slices/stacks the per-layer cache with *integer* dynamic-slice /
-    dynamic-update-slice, which every backend does in place — XLA:CPU
-    legalizes low-precision float DUS via f32, rewriting the whole stacked
-    cache with converts each layer (§Perf L3)."""
-    def f(a):
-        if jnp.issubdtype(a.dtype, jnp.floating):
-            return jax.lax.bitcast_convert_type(
-                a, _UINT_OF[jnp.dtype(a.dtype).itemsize])
-        return a
-    return jax.tree.map(f, tree)
-
-
-@jax.named_scope("pool")
-def _cache_unbits(tree, dtypes):
-    return jax.tree.map(
-        lambda a, dt: jax.lax.bitcast_convert_type(a, dt)
-        if a.dtype != dt else a, tree, dtypes)
-
-
 def decode_step(params, cfg: ModelConfig, cache, token, pos_len, *,
                 page_table=None, page_size: int = 0, live=None,
                 frame_table=None, slot_idx=None):
@@ -568,9 +560,16 @@ def decode_step(params, cfg: ModelConfig, cache, token, pos_len, *,
     pages the Loki selection attended — the scheduler promotes HOST
     winners and replays.
 
+    The scan families carry the stacked cache through the layer loop
+    (the weights are its ``xs``): each layer's K/V rows are written into
+    the stack and the attention reads its layer there, so a paged float
+    pool is never copied, relaid out or restacked on the Pallas route.
+
     Device scopes (``jax.named_scope``, in the ops' metadata): ``pool``
-    moves the page pool (the layer scan's cache carry and u16 views, the
-    packed slot gather/scatter, K/V row writes and logical gathers),
+    moves the page pool (the layer loop, whose own ops are the weights'
+    per-layer slices, the packed slot gather/scatter, K/V row writes,
+    per-layer slices of the components not written in place, and logical
+    gathers),
     ``attn`` the token mixer (norm, QKV, rope, PCA projection, the
     attention kernel or its jnp route, output projection), ``mlp`` the
     feed-forward, ``head`` the embedding lookup, final norm and LM head."""
@@ -600,34 +599,40 @@ def decode_step(params, cfg: ModelConfig, cache, token, pos_len, *,
 
     if scan and not hetero:
         kind = layer_kind(cfg, 0)
-        dtypes = jax.tree.map(lambda a: a.dtype, layers_in)
-        xs = (params["layers"], _cache_bits(layers_in))
+        xs = (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32))
         if ranks is not None:
             xs = xs + (ranks,)
+        # one-tier float page pools are decoded in place in the stack;
+        # every other component (recurrent state, cross K/V, quantized or
+        # tiered pools, contiguous caches) has its layer sliced out and
+        # put back into the carry
+        in_place = (page_table is not None and not tiered
+                    and "attn" in layers_in
+                    and "k_scale" not in layers_in["attn"])
+        own = {k: in_place and k == "attn" for k in layers_in}
 
         def body(carry, pc):
-            p, cbits = pc[0], pc[1]
-            rk = pc[2] if len(pc) > 2 else None
-            x, win = carry if tiered else (carry, None)
-            c = _cache_unbits(cbits, dtypes)
-            x, c, w = _layer_decode(p, c, x, pos_len, cfg, kind,
+            x, layers, win = carry
+            lyr = pc[1]
+            c = {k: v if own[k] else _at_layer(v, lyr)
+                 for k, v in layers.items()}
+            x, c, w = _layer_decode(pc[0], c, x, pos_len, cfg, kind,
                                     page_table=page_table,
                                     page_size=page_size, live=live,
-                                    frame_table=frame_table, rank=rk)
-            if tiered:
-                return (x, win | w), _cache_bits(c)
-            return x, _cache_bits(c)
+                                    frame_table=frame_table,
+                                    rank=pc[2] if len(pc) > 2 else None,
+                                    layer=lyr if in_place else None)
+            layers = {k: v if own[k] else _put_layer(layers[k], v, lyr)
+                      for k, v in c.items()}
+            return (x, layers, win | w if tiered else None), None
 
-        # the scan's per-layer slicing and restacking of the cache is
-        # the pool's (the body's own ops carry their inner scopes)
+        # the loop's own ops (the weights' per-layer slices) are the
+        # pool's; the body's ops carry their inner scopes
         with jax.named_scope("pool"):
-            if tiered:
-                win0 = jnp.zeros(page_table.shape, bool)
-                (x, win), new_bits = jax.lax.scan(body, (x, win0), xs)
-            else:
-                win = None
-                x, new_bits = jax.lax.scan(body, x, xs)
-        new_cache = {"layers": _cache_unbits(new_bits, dtypes)}
+            win0 = jnp.zeros(page_table.shape, bool) if tiered else None
+            (x, layers, win), _ = jax.lax.scan(body, (x, layers_in, win0),
+                                               xs)
+        new_cache = {"layers": layers}
     elif hetero:
         # per-layer static windows: unroll over the stacked leaves so each
         # layer's mask/kernel window and page-table group row are compile-
@@ -639,8 +644,7 @@ def decode_step(params, cfg: ModelConfig, cache, token, pos_len, *,
         new_layers = layers_in
         for i in range(cfg.n_layers):
             p = jax.tree.map(lambda a: a[i], params["layers"])
-            with jax.named_scope("pool"):
-                c = jax.tree.map(lambda a: a[i], new_layers)
+            c = _at_layer(new_layers, i)
             pt_i = page_table
             if page_table is not None and page_table.ndim == 3:
                 pt_i = page_table[:, gids[i]]
@@ -649,10 +653,7 @@ def decode_step(params, cfg: ModelConfig, cache, token, pos_len, *,
                 page_table=pt_i, page_size=page_size, live=live,
                 rank=None if ranks is None else ranks[i],
                 sliding_window=cfg.layer_window(i))
-            with jax.named_scope("pool"):
-                new_layers = jax.tree.map(
-                    lambda full, one: jax.lax.dynamic_update_index_in_dim(
-                        full, one.astype(full.dtype), i, 0), new_layers, c)
+            new_layers = _put_layer(new_layers, c, i)
         new_cache = {"layers": new_layers}
     else:
         # non-scan families (xlstm) have no paged attention: no tiering

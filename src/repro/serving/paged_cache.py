@@ -74,7 +74,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import dispatch
 from repro.kernels.layout import page_slice, put_rows
+from repro.kernels.pool_write import pool_write_rows
+from repro.kernels.tuning import LANE
 
 TRASH_PAGE = 0
 
@@ -122,17 +125,23 @@ def gather_logical(pool, page_table, page_size: int):
     return pages.transpose(2, 3, 4, 0, 1).reshape(b, n * page_size, h, w)
 
 
-def _scatter_rows(pool, rows, new):
-    """pool (Hkv, W, R) <- new (N, Hkv, W) at row ids (N,), bitcast to
-    uint so low-precision scatters stay in-place on every backend
-    (§Perf L3)."""
+def _scatter_rows(pool, rows, new, layer=None):
+    """pool (Hkv, W, R) <- new (N, Hkv, W) at row ids (N,); with ``layer``,
+    into that layer of stacked pools (L, Hkv, W, R). The jnp route: the
+    scatter runs on the bits as uint, because XLA:CPU legalizes a
+    low-precision float scatter through f32 and would rewrite the whole
+    pool with converts on every write (§Perf L3)."""
     dt = pool.dtype
     uint = _UINT_OF.get(jnp.dtype(dt).itemsize) if jnp.issubdtype(
         dt, jnp.floating) else None
     p_view = jax.lax.bitcast_convert_type(pool, uint) if uint else pool
     n_view = jax.lax.bitcast_convert_type(new.astype(dt), uint) if uint \
         else new.astype(dt)
-    out = p_view.at[:, :, rows].set(n_view.transpose(1, 2, 0), mode="drop")
+    if layer is None:
+        out = p_view.at[:, :, rows].set(n_view.transpose(1, 2, 0),
+                                        mode="drop")
+    else:
+        out = p_view.at[layer, :, :, rows].set(n_view, mode="drop")
     return jax.lax.bitcast_convert_type(out, dt) if uint else out
 
 
@@ -145,9 +154,22 @@ def token_rows(page_table, pos, page_size: int):
 
 
 @jax.named_scope("pool")
-def write_token_rows(pool, new, page_table, pos, page_size: int):
-    """Decode-step write: new (B, Hkv, W) at logical positions pos (B,)."""
-    return _scatter_rows(pool, token_rows(page_table, pos, page_size), new)
+def write_token_rows(pool, new, page_table, pos, page_size: int, *,
+                     layer=None, backend: str = "xla"):
+    """Decode-step write: new (B, Hkv, W) at logical positions pos (B,).
+
+    ``layer`` (traced int32): ``pool`` is every layer's pools stacked
+    (L, Hkv, W, R) and the rows land in this layer. On the Pallas
+    ``backend`` that is the aliased row-write kernel
+    (kernels/pool_write.py), in place in the stack; otherwise, and for a
+    pool with no whole 128-row tiles on TPU, a jnp scatter into it."""
+    rows = token_rows(page_table, pos, page_size)
+    if layer is not None and dispatch.resolve_backend(backend) == "pallas":
+        interpret = dispatch._interpret(None)
+        if interpret or pool.shape[-1] % LANE == 0:
+            return pool_write_rows(pool, new, layer, rows,
+                                   interpret=interpret)
+    return _scatter_rows(pool, rows, new, layer)
 
 
 @jax.named_scope("pool")

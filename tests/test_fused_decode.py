@@ -325,6 +325,36 @@ def test_fused_paged_matches_contiguous(g, bs, ps):
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("layer", ["first", "last"])
+def test_fused_reads_its_layer_of_stacked_pools(layer):
+    """With the layers' pools stacked (L, Hkv, W, R) and a layer index,
+    the kernel returns exactly what it returns on that layer's own pools:
+    the index only moves every DMA's source into that layer."""
+    b, hkv, g, s, dim, bs, n_layers = 2, 2, 4, 256, 64, 32, 3
+    q, _, _ = _setup(b, hkv, g, s, dim, seed=5)
+    proj = _orthogonal(hkv, dim, seed=5)
+    pools = []
+    for i in range(n_layers):
+        _, k, v = _setup(b, hkv, g, s, dim, seed=10 + i)
+        k_hat = jnp.einsum("bshd,hde->bshe", k, proj)
+        pools.append(_paged_pool(k_hat, v, bs, 64, seed=1))
+    table = pools[0][2]
+    k_stack = jnp.stack([p[0] for p in pools])
+    v_stack = jnp.stack([p[1] for p in pools])
+    lyr = 0 if layer == "first" else n_layers - 1
+    cur = jnp.array([s, 100], jnp.int32)
+    kw = dict(d=16, k_blocks=3, block_size=bs, local_window=8,
+              page_table=table, page_size=64, interpret=True)
+    q_hat = _grouped_q(q, proj, hkv)
+    want = fused_loki_decode(q_hat, pools[lyr][0], pools[lyr][1], cur, **kw)
+    got = fused_loki_decode(q_hat, k_stack, v_stack, cur,
+                            layer=jnp.int32(lyr), **kw)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    other = fused_loki_decode(q_hat, k_stack, v_stack, cur,
+                              layer=jnp.int32(1), **kw)
+    assert not np.array_equal(np.asarray(other), np.asarray(want))
+
+
 def test_dispatch_paged_pallas_matches_oracle():
     """End-to-end dispatch with a page table: backend='pallas' (paged
     kernels) equals the group-shared jnp oracle gathering through the same

@@ -11,15 +11,21 @@ The topology is described inside a fixture, never at import time: only
 one process at a time may load the TPU library, and pytest-xdist workers
 all import this file.
 """
+import dataclasses
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import get_config
+from repro.configs.base import PageLayout
 from repro.core import dispatch
 from repro.kernels import fused_decode as FD
 from repro.kernels import gather_attention as GA
 from repro.kernels import layout
+from repro.models import lm
 
 B, HKV, G, D, PS, SMAX = 4, 2, 8, 128, 128, 2048
 MAX_PAGES = SMAX // PS
@@ -133,3 +139,81 @@ def test_fp16_pool_is_refused_and_guarded(one_chip):
     dispatch.check_tpu_pool(jnp.float16, platform="cpu")
     for dt in (jnp.float32, jnp.bfloat16, jnp.int8, jnp.float8_e4m3fn):
         dispatch.check_tpu_pool(dt, platform="tpu")
+
+
+# ------------------------------------------------ the whole decode step
+
+STEP_SLOTS, STEP_SMAX = 4, 11264
+STEP_ROWS = (1 + STEP_SLOTS * (STEP_SMAX // PS)) * PS
+#: ops that only hand the stacked pool along: the program's arguments,
+#: the layer loop and its carry
+_PLUMBING = {"parameter", "get-tuple-element", "tuple", "while"}
+#: the kernels that address the pool in place
+_POOL_KERNELS = ("pool_write_rows", "fused_loki_decode")
+
+
+def _pool_ops(hlo_text, n_rows):
+    """(name, opcode) of every instruction whose result has a dimension of
+    ``n_rows``: the ops that hold, or copy, a whole pool or layer."""
+    dim = re.compile(r"[\[,]%d[\],]" % n_rows)
+    found = []
+    for line in hlo_text.splitlines():
+        m = re.match(r"\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*)", line)
+        if not m:
+            continue
+        op = re.search(r"\s([a-z][a-z0-9\-]*)\(", " " + m.group(2))
+        if op and dim.search(m.group(2)[:op.start()]):
+            found.append((m.group(1), op.group(1)))
+    return found
+
+
+@pytest.mark.parametrize("program", ["masked", "packed"])
+def test_decode_step_writes_the_stacked_pool_in_place(one_chip, monkeypatch,
+                                                      program):
+    """Qwen2.5-3B's decode step at 2 layers (bf16 pool, Hkv 2, D 128, page
+    128) compiled for v5e, with its Pallas kernels compiled for Mosaic:
+    the stacked pool rides the layer loop's carry, and no op but the
+    aliased row-write kernel and the Loki kernel makes anything of its
+    size (no layout copy, bitcast, layer slice or restack). Temporaries
+    stay under one layer's pool."""
+    monkeypatch.setattr(dispatch, "_interpret", lambda interpret: False)
+    cfg = get_config("qwen2.5-3b").replace(
+        n_layers=2, param_dtype="bfloat16").with_policy(
+        "loki_block", k_f=0.25, d_f=0.25).with_layout(PageLayout("bf16"))
+    cfg = cfg.replace(loki=dataclasses.replace(cfg.loki, backend="pallas",
+                                               local_window=16))
+
+    def sds(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    n_pages = STEP_ROWS // PS
+    params = sds(jax.eval_shape(lambda k: lm.init(k, cfg),
+                                jax.random.PRNGKey(0)))
+    cache = sds(jax.eval_shape(lambda: lm.init_paged_cache(
+        cfg, n_pages, PS, jnp.float32, n_slots=STEP_SLOTS)))
+    assert cache["layers"]["attn"]["k"].shape == (2, HKV, D, STEP_ROWS)
+    b = STEP_SLOTS if program == "masked" else STEP_SLOTS // 2
+    pack = {} if program == "masked" else {"slot_idx": i32(b)}
+    fn = lambda p, c, t, n, pt, **kw: lm.decode_step(
+        p, cfg, c, t, n, page_table=pt, page_size=PS, **kw)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, i32(b), i32(b), i32(b, STEP_SMAX // PS),
+        **pack).compile()
+    text = compiled.as_text()
+    ops = _pool_ops(text, STEP_ROWS)
+    assert any(op == "while" for _, op in ops), "the pool left the carry"
+    kernels = [n for n, op in ops if op == "custom-call"]
+    assert kernels and all(n.startswith(_POOL_KERNELS) for n in kernels), \
+        kernels
+    stray = [(n, op) for n, op in ops
+             if op not in _PLUMBING and op != "custom-call"]
+    assert stray == [], stray
+    layer_pool = HKV * D * STEP_ROWS * 2
+    ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes < layer_pool, (ma.temp_size_in_bytes,
+                                                layer_pool)
+    assert ma.alias_size_in_bytes >= 2 * 2 * layer_pool
