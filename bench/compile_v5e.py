@@ -41,7 +41,8 @@ def main(cells, slots=None) -> None:
     for name in cells:
         cell = spec.load_cell(name)
         eng = cell.traffic["engine"]
-        sc, cfg = harness.program_config(cell.config, cell.traffic, True)
+        sc, cfg = harness.program_config(cell.config, cell.traffic, True,
+                                         cell.reduced)
         cfg = cfg.replace(loki=dataclasses.replace(cfg.loki,
                                                    backend="pallas"))
         ps, smax = sc.pool.page_size, eng["smax"]

@@ -83,6 +83,19 @@ def decode_step_flops(m: Dims, contexts: Iterable[int]) -> float:
     return float(mm + pca + attn)
 
 
+def prefill_chunk_flops(m: Dims, start: int, n: int) -> float:
+    """Model FLOPs of one prefill chunk of ``n`` prompt tokens at
+    positions ``start`` .. ``start + n - 1``: the matmuls, the rotation
+    of the queries and keys into the PCA basis, and exact causal
+    attention of each token over every earlier position and itself. The
+    chunk's logits, which the engine does not use, are not counted."""
+    mm = 2 * n * m.layers * layer_matmul_params(m)
+    pca = 2 * m.layers * n * (m.heads + m.kv_heads) * m.head_dim ** 2
+    keys = n * start + n * (n + 1) // 2         # sum of each token's keys
+    attn = 4 * m.layers * m.heads * m.head_dim * keys
+    return float(mm + pca + attn)
+
+
 def loki_kernel_cost(m: Dims, contexts: Iterable[int]) -> Tuple[float, float]:
     """(FLOPs, HBM bytes) the fused Loki decode kernel needs for one
     decode step, summed over slots, layers and KV heads. Per (slot, KV

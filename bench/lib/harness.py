@@ -9,9 +9,11 @@ Order of a run:
      projections, calibrated once per checkout with the reference and
      kept under ``bench/.cache``;
   3. the engine, with every program the cell's traffic reaches warmed
-     (and the cell's sessions prefilled); the trace guard is sealed;
+     (and the cell's sessions prefilled); the trace guard is sealed; an
+     open mix's arrivals start, and run ``warm_seconds`` before the window;
   4. the window (``--seconds``), timed on the harness's clock; with
-     ``--trace 1`` its last ``trace_seconds`` under the profiler;
+     ``--trace 1`` its last ``trace_seconds`` under the profiler; an open
+     mix's requests are submitted as they come due, between ticks;
   5. the device's memory peak; the engine freed; the reference run over a
      seeded sample of the sessions the window served (the longest among
      them); the metrics.
@@ -65,13 +67,29 @@ def _merge(base: dict, over: Optional[dict]) -> dict:
     return out
 
 
-def program_config(cfg: dict, traffic: dict, check_registry: bool):
+def program_norm_eps(pc) -> float:
+    """The RMSNorm epsilon the program serves with: its model config's,
+    where it has one, else the default of its norm."""
+    eps = getattr(pc, "norm_eps", None)
+    if eps is None:
+        import inspect
+        from repro.models import layers
+        eps = inspect.signature(layers.norm_apply).parameters["eps"].default
+    return float(eps)
+
+
+def program_config(cfg: dict, traffic: dict, check_registry: bool,
+                   reduced=()):
     """The program's ModelConfig for a configuration file, through the
-    launcher's ``ServeConfig`` (policy, k_f/d_f, page layout), at the
-    file's depth and widths. Stops when the program's own record of the
-    architecture disagrees with the file on anything but ``reduced``."""
+    launcher's ``ServeConfig`` (policy, k_f/d_f, page layout), with the
+    fields that the configuration's reference module says the file sets
+    (``program_fields``). Stops when the program's own record of the
+    architecture disagrees with the file on a key not in ``reduced``, or
+    where the reference finds the program departing from the file
+    (``departures``)."""
     from repro.configs import get_config
     from repro.launch import serve
+    ref = spec.reference(cfg)
     sv, eng = cfg["serving"], traffic["engine"]
     sc = serve.ServeConfig(
         engine=serve.EngineSection(
@@ -81,25 +99,71 @@ def program_config(cfg: dict, traffic: dict, check_registry: bool):
         pool=serve.PoolSection(page_size=sv["page_size"],
                                prefill_chunk=sv["prefill_chunk"]),
         layout=serve.LayoutSection(spec=sv["pool_layout"]))
-    widths = dict(n_layers=cfg["num_hidden_layers"],
-                  d_model=cfg["hidden_size"],
-                  n_heads=cfg["num_attention_heads"],
-                  n_kv_heads=cfg["num_key_value_heads"],
-                  d_ff=cfg["intermediate_size"], vocab=cfg["vocab_size"],
-                  rope_theta=float(cfg["rope_theta"]))
+    resolved = sc.resolve_model()
+    fields = ref.program_fields(cfg, resolved)
     if check_registry:
         pub = get_config(cfg["arch"])
-        for k, v in widths.items():
-            if k != "n_layers" and getattr(pub, k) != v:
+        for k, (key, v) in fields.items():
+            if key not in reduced and getattr(pub, k) != v:
                 raise ValueError(f"program's {cfg['arch']} has {k}="
                                  f"{getattr(pub, k)}, the file {v}")
-    pc = sc.resolve_model().replace(**widths)
-    if not (pc.qkv_bias and pc.mlp == "swiglu" and pc.norm == "rms"
-            and pc.rope and pc.resolved_head_dim * pc.n_heads == pc.d_model
-            and pc.loki.local_window == sv["local_window"]
-            and pc.loki.transform == cfg["calibration"]["transform"]):
-        raise ValueError(f"program config departs from the file: {pc}")
+    pc = resolved.replace(**{k: v for k, (_, v) in fields.items()})
+    why = ref.departures(pc, cfg, program_norm_eps(pc))
+    if pc.loki.local_window != sv["local_window"] \
+            or pc.loki.transform != cfg["calibration"]["transform"]:
+        why.append(f"Loki serving {pc.loki}")
+    if why:
+        raise ValueError(f"program config departs from the file: {why}")
     return sc, pc
+
+
+def warm_programs(eng, guard, seed: int, vocab: int) -> None:
+    """Trace and compile every program an open mix can reach, through
+    the engine's public calls: for each decode batch width the engine
+    serves (each packed bucket below ``n_slots``, then ``n_slots``, the
+    full-width step) that many short requests, one prefill chunk each,
+    which stay until all of them decode together, then finish; then a
+    request that opens with a live one's first tokens, whose first write
+    copies the page they share (a prompt that opens with the first token
+    of a live request's first page does so too). Stops where a program
+    was not traced."""
+    from repro.serving.engine import Request
+    from repro.serving.lifecycle import TERMINAL, Status
+    n, chunk = eng.n_slots, eng.prefill_chunk
+    widths = [1 << i for i in range(n.bit_length()) if 1 << i < n] + [n]
+    rng = np.random.Generator(np.random.PCG64([seed, 2]))
+
+    def prompt(head=()):
+        return np.concatenate([head, rng.integers(
+            0, vocab, chunk + 1 - len(head))]).astype(np.int32)
+
+    def serve(warm, until):
+        for _ in range(10_000):
+            if all(r.status in until for r in warm):
+                return
+            if any(r.status in TERMINAL - until for r in warm):
+                break
+            eng.tick()
+        raise RuntimeError("a warm-up request did not get on: "
+                           + str([str(r.status) for r in warm]))
+
+    done = frozenset({Status.DONE})
+    for b in widths:
+        warm = [Request(rid=-1 - i, prompt=prompt(), max_new=b + 2)
+                for i in range(b)]
+        for r in warm:
+            eng.submit(r)
+        serve(warm, done)
+    live = Request(rid=-1, prompt=prompt(), max_new=4)
+    eng.submit(live)
+    serve([live], frozenset({Status.DECODE}))
+    twin = Request(rid=-2, prompt=prompt(live.prompt[:8]), max_new=2)
+    eng.submit(twin)
+    serve([live, twin], done)
+    want = ["prefill_chunk", "decode_step", "copy_cache_page"] + [
+        f"decode_step_packed[b{b}]" for b in widths[:-1]]
+    if not all(guard.traces.get(w) for w in want):
+        raise RuntimeError(f"warm-up left programs untraced: {guard.traces}")
 
 
 def projections(params, cfg: dict, smax: int) -> np.ndarray:
@@ -212,7 +276,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     eng_cfg = tr["engine"]
     smax = eng_cfg["smax"]
     sc, pcfg = program_config(cfg, tr, check_registry="config" not in (
-        overrides or {}))
+        overrides or {}), reduced=cell.reduced)
     params = weights.make_params(cfg, pcfg)
     proj = projections(params, cfg, smax)
     params = weights.with_projections(params, proj)
@@ -227,30 +291,49 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
         backend=sc.engine.backend,
         prefill_budget=eng_cfg.get("prefill_budget"), trace_guard=guard)
     terminal = frozenset(TERMINAL)
-    plan = traffic_gen.plan(tr, seed, cfg["vocab_size"])
+    open_loop = tr["kind"] == "open"
+    plan = traffic_gen.plan(tr, seed, cfg["vocab_size"],
+                            seconds if open_loop else None)
     prompts = {p.rid: p.prompt for p in plan}
     clock = time.perf_counter
 
     reqs = [Request(rid=p.rid, prompt=p.prompt.copy(), max_new=p.max_new)
             for p in plan]
-    for r in reqs:
-        eng.submit(r)
-    ticks = 0
-    while not all(r.status is Status.DECODE for r in reqs):
-        if any(r.status in terminal for r in reqs) or ticks > 10_000:
-            raise RuntimeError("a session ended during set-up: "
-                               + str([str(r.status) for r in reqs]))
-        eng.tick()
-        ticks += 1
-    for _ in range(tr.get("warm_ticks", 2)):
-        eng.tick()
-    jax.block_until_ready(eng.cache)
-    t0 = clock()
-    seen = [drive.Seen(r, n_out=len(r.out)) for r in reqs]
-    guard.seal()
-    traces0 = dict(guard.traces)
+    if open_loop:
+        warm_programs(eng, guard, seed, cfg["vocab_size"])
+        jax.block_until_ready(eng.cache)
+        t_sched = clock()
+        seen = []
+        arrivals = drive.Arrivals(reqs, [t_sched + p.due for p in plan],
+                                  clock, terminal, Status.QUEUED)
+        guard.seal()
+        traces0 = dict(guard.traces)
+        setup_s = time.time() - t_start
+        # the window opens when its block of arrivals begins, after the
+        # warm stretch
+        t0 = t_sched + tr["warm_seconds"]
+        drive.run_open_until(eng, drive.Window(t0=t_sched, seen=seen),
+                             seen, t0, clock, arrivals)
+        ticks_before = len(arrivals.queued)
+    else:
+        for r in reqs:
+            eng.submit(r)
+        ticks = 0
+        while not all(r.status is Status.DECODE for r in reqs):
+            if any(r.status in terminal for r in reqs) or ticks > 10_000:
+                raise RuntimeError("a session ended during set-up: "
+                                   + str([str(r.status) for r in reqs]))
+            eng.tick()
+            ticks += 1
+        for _ in range(tr.get("warm_ticks", 2)):
+            eng.tick()
+        jax.block_until_ready(eng.cache)
+        t0 = clock()
+        seen = [drive.Seen(r, n_out=len(r.out)) for r in reqs]
+        guard.seal()
+        traces0 = dict(guard.traces)
+        setup_s = time.time() - t_start
     fallbacks0 = eng.n_packed_fallbacks + eng.n_backend_fallbacks
-    setup_s = time.time() - t_start
 
     win = drive.Window(t0=t0, seen=seen)
     until = t0 + seconds
@@ -270,34 +353,43 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
         # compile cache: the trace guard sees only the first
         compiles.append([event, str(kw.get("fun_name", "")), secs,
                          clock() - t0])
+
+    def drive_until(w, t):
+        if open_loop:
+            drive.run_open_until(eng, w, seen, t, clock, arrivals)
+        else:
+            drive.run_ticks_until(eng, w, seen, t, clock)
     gc.callbacks.append(gc_watch)
     jax.monitoring.register_event_duration_secs_listener(compile_watch)
     traced, red = None, None
     trace_dir = os.path.join(spec.BENCH, ".out", f"trace-{workload}")
     if trace:
         split = max(until - tr["trace_seconds"], t0)
-        drive.run_ticks_until(eng, win, seen, split, clock)
+        drive_until(win, split)
         shutil.rmtree(trace_dir, ignore_errors=True)
         jax.profiler.start_trace(trace_dir)
         traced = drive.Window(t0=clock(), seen=seen)
         with TraceAnnotation("bench.window"):
-            drive.run_ticks_until(eng, traced, seen, until, clock)
+            drive_until(traced, until)
         jax.profiler.stop_trace()
         win.ticks += traced.ticks
         win.tick_ends += traced.tick_ends
         win.tick_calls += traced.tick_calls
         win.t1 = traced.t1
     else:
-        drive.run_ticks_until(eng, win, seen, until, clock)
+        drive_until(win, until)
     jax.monitoring.unregister_event_duration_listener(compile_watch)
     gc.callbacks.remove(gc_watch)
     if guard.traces != traces0:
         raise RuntimeError(f"programs traced in the window: {traces0} -> "
                            f"{guard.traces}")
     fallbacks = eng.n_packed_fallbacks + eng.n_backend_fallbacks - fallbacks0
+    if open_loop:
+        win.unsent = [due for due, _, _ in arrivals.todo if due < win.t1]
 
     device = _device_info(jax, devices)
-    failed = sum(1 for r in reqs if r.status in terminal
+    sent = [x.req for x in seen]
+    failed = sum(1 for r in sent if r.status in terminal
                  and r.status is not Status.DONE)
     log(f"window {win.seconds:.3f} s, {win.ticks} ticks, "
         f"{drive.tokens_in(win)} tokens, statuses {drive.statuses(win)}, "
@@ -309,7 +401,9 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
         device["window_s"] = red["window_s"]
 
     # free the engine's pool before the reference runs beside the weights
-    served = pick(reqs, seed, tr["check"]["requests"])
+    served = pick([x.req for x in seen if x.req.status is Status.DONE
+                   and x.tokens and win.t0 <= x.tokens[-1] <= win.t1]
+                  if open_loop else reqs, seed, tr["check"]["requests"])
     del eng
     gc.collect()
     t_check = time.time()
@@ -325,7 +419,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     result = {
         # nothing served to compare is not a pass
         "correct": check["value"] is not None and check["value"] <= limit,
-        "attempted": len(reqs),
+        "attempted": len(sent),
         "failed": failed,
         "metrics": metrics,
         "device": device,
@@ -340,6 +434,9 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
                         "longest_ticks_ms": drive.longest_ticks(win),
                         "gc_pauses_ms": gc_pauses,
                         "compile_events": compiles}
+    if open_loop:
+        result["window"]["arrivals"] = drive.arrival_stats(
+            win, arrivals.queued[ticks_before:])
     if keep is not None:
         keep.update(run=run_data, check=check)
     log(f"compared {len(served)} requests, {check['tokens']} served tokens "

@@ -11,7 +11,10 @@ arguments. ``reduce`` computes, inside the host span ``bench.window``:
   * the device's idle time, integrated over each idle interval and
     attributed to the innermost host span the engine's thread was in
     (an ``engine.*`` span, else a ``bench.*`` span, else none), and the
-    part of it outside ``engine.decode.sync``.
+    part of it outside ``engine.decode.sync``;
+  * the arguments of the engine's ``engine.admit`` spans (``rid``,
+    ``queued_ms``) and ``engine.prefill`` spans (``rid``, ``tokens``),
+    in the order they began.
 
 An op's scope is in its ``op_name`` metadata (the JAX name stack,
 ``jit(decode_step)/pool/while/body/attn/...``). A TPU ``XLA Ops`` event
@@ -277,7 +280,21 @@ def reduce(events: dict) -> dict:
         "engine_spans": sum(1 for e in events["host"]
                             if e[0].startswith("engine.")
                             and e[2] > t0 and e[1] < t1),
+        "admits": _args(events["host"], "engine.admit", t0, t1,
+                        ("rid", "queued_ms")),
+        "prefills": _args(events["host"], "engine.prefill", t0, t1,
+                          ("rid", "tokens")),
     }
+
+
+def _args(host: List[list], name: str, t0: float, t1: float,
+          keys: Tuple[str, ...]) -> List[List[int]]:
+    """The arguments ``keys`` of every span ``name`` that begins inside
+    [t0, t1), in the order they began."""
+    return [[int(float(e[3][k])) for k in keys]
+            for e in sorted(host, key=lambda e: e[1])
+            if e[0] == name and t0 <= e[1] < t1
+            and all(k in e[3] for k in keys)]
 
 
 @functools.lru_cache(maxsize=1)

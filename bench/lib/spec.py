@@ -35,6 +35,7 @@ class Cell:
     traffic: dict          # the traffic file
     limits: dict           # bench/limits/<cell>.json
     chips: int
+    reduced: List[str]     # the configuration's keys changed from its source
     end_to_end: List[dict]
     per_layer: List[dict]
 
@@ -56,7 +57,8 @@ def load_cell(workload: str) -> Cell:
     traffic = _json(os.path.join(BENCH, "traffic", w["traffic"] + ".json"))
     limits = _json(os.path.join(BENCH, "limits", workload + ".json"))
     return Cell(workload, cfg, traffic, limits, int(w["chips"]),
-                bench["end_to_end"], bench["per_layer"])
+                list(configs[w["config"]]["reduced"]), bench["end_to_end"],
+                bench["per_layer"])
 
 
 def reference(cfg: dict):

@@ -3,21 +3,45 @@
 The benchmark, not the program, makes the weights, so the reference can
 take them without taking anything the program made. The leaves follow the
 program's parameter tree (read from ``jax.eval_shape`` of its ``lm.init``:
-names, shapes and dtypes); each leaf is drawn by what its name says it is,
-and a leaf whose name is unknown here stops the run. One jitted call draws
+names, shapes and dtypes); each leaf is drawn by the rule that the
+configuration's reference module declares for its name (the rules below,
+or its own), and a leaf with no rule stops the run. One jitted call draws
 every leaf on the device in the dtype it is served in.
 """
 from __future__ import annotations
 
-import functools
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-MATRICES = ("wq", "wk", "wv", "wo", "w_in", "w_out")
-BIASES = ("bq", "bk", "bv")
+from bench.lib import spec
+
+
+def fan_in(axis: int):
+    """Rule of a weight matrix: normal, scaled by the inverse square root
+    of its size along ``axis`` (its fan-in)."""
+    def draw(k, shape, dt, w):
+        return jax.random.normal(k, shape, dt) * jnp.asarray(
+            shape[axis] ** -0.5, dt)
+    return draw
+
+
+def bias(k, shape, dt, w):
+    """Rule of a bias: normal with the configuration's ``bias_std``."""
+    return (float(w["bias_std"]) * jax.random.normal(k, shape)).astype(dt)
+
+
+def norm_scale(k, shape, dt, w):
+    """Rule of a norm's gain: 1 plus normal with ``norm_scale_std``."""
+    return (1.0 + float(w["norm_scale_std"])
+            * jax.random.normal(k, shape)).astype(dt)
+
+
+def identity(k, shape, dt, w):
+    """Rule of the PCA projections until they are calibrated."""
+    return jnp.broadcast_to(jnp.eye(shape[-1], dtype=dt), shape)
 
 
 def _leaf_specs(shapes) -> Tuple[tuple, ...]:
@@ -31,42 +55,28 @@ def _leaf_specs(shapes) -> Tuple[tuple, ...]:
     return tuple(out)
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2, 3))
-def _draw(key, specs, bias_std: float, scale_std: float):
-    leaves = []
-    for i, (names, shape, dtype) in enumerate(specs):
-        k = jax.random.fold_in(key, i)
-        name = names[-1]
-        dt = jnp.dtype(dtype)
-        if name == "table":                       # (vocab, d_model)
-            w = jax.random.normal(k, shape, dt) * jnp.asarray(
-                shape[-1] ** -0.5, dt)
-        elif name in MATRICES:                    # (L, fan_in, fan_out)
-            w = jax.random.normal(k, shape, dt) * jnp.asarray(
-                shape[-2] ** -0.5, dt)
-        elif name in BIASES:
-            w = (bias_std * jax.random.normal(k, shape)).astype(dt)
-        elif name == "scale":                     # RMSNorm gains
-            w = (1.0 + scale_std * jax.random.normal(k, shape)).astype(dt)
-        elif name == "pca":                       # identity until calibrated
-            w = jnp.broadcast_to(jnp.eye(shape[-1], dtype=dt), shape)
-        else:
-            raise ValueError(f"no rule to draw parameter {names}")
-        leaves.append(w)
-    return leaves
-
-
 def make_params(cfg: dict, prog_cfg):
     """The program's parameter tree for configuration file ``cfg``, drawn
-    from its weight seed on the default device."""
+    from its weight seed on the default device, each leaf by the rule
+    that the configuration's reference module gives its name
+    (``LEAVES``: leaf name -> ``rule(key, shape, dtype, weights)``)."""
     from repro.models import lm
     shapes = jax.eval_shape(lambda k: lm.init(k, prog_cfg),
                             jax.random.PRNGKey(0))
     _, treedef = jax.tree_util.tree_flatten(shapes)
+    specs = _leaf_specs(shapes)
+    rules = spec.reference(cfg).LEAVES
+    for names, _, _ in specs:
+        if names[-1] not in rules:
+            raise ValueError(f"no rule to draw parameter {names}")
     w = cfg["weights"]
-    leaves = _draw(jax.random.PRNGKey(np.uint32(w["seed"] % 2 ** 32)),
-                   _leaf_specs(shapes), float(w["bias_std"]),
-                   float(w["norm_scale_std"]))
+
+    @jax.jit
+    def draw(key):
+        return [rules[names[-1]](jax.random.fold_in(key, i), shape,
+                                 jnp.dtype(dtype), w)
+                for i, (names, shape, dtype) in enumerate(specs)]
+    leaves = draw(jax.random.PRNGKey(np.uint32(w["seed"] % 2 ** 32)))
     return jax.tree_util.tree_unflatten(treedef, leaves)
 
 

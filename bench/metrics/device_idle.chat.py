@@ -1,0 +1,9 @@
+"""Share of the traced window in which no operation ran on the device:
+1 - (union of device-busy intervals) / (traced window), in percent."""
+
+
+def read(run):
+    if run.trace is None:
+        return None
+    t = run.trace
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])

@@ -3,7 +3,10 @@ decode attention, in float32 jax.numpy at highest matmul precision.
 
 It imports nothing of the program. It reads the weights the benchmark
 made (``bench/lib/weights.py``) by their leaf names, and the PCA
-projections it calibrates itself (``calibrate``).
+projections it calibrates itself (``calibrate``). It also tells the
+harness what a Qwen2 configuration file asks of the program: the widths
+it sets (``program_fields``), where the program would depart from it
+(``departures``) and how each weight is drawn (``LEAVES``).
 
 The published architecture (Qwen2, ``config.json``): token embedding;
 per layer RMSNorm, q/k/v projections with bias, rotary position embedding
@@ -33,17 +36,66 @@ per-tensor (weights) or per-row (activations) scale.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench.lib import weights as W
 
 HIGHEST = jax.lax.Precision.HIGHEST
 NEG_INF = -1e30
 ROWS = 256          # query rows per attention block
 PAD = 1024          # sequences pad to a multiple of this (fewer programs)
 VOCAB_CHUNKS = 8    # the head reads its table in this many slices
+
+
+# how each leaf of the program's parameter tree is drawn, by its name
+LEAVES = {
+    "table": W.fan_in(-1),                        # (vocab, d_model)
+    **{m: W.fan_in(-2) for m in                   # (L, fan_in, fan_out)
+       ("wq", "wk", "wv", "wo", "w_in", "w_out")},
+    **{b: W.bias for b in ("bq", "bk", "bv")},
+    "scale": W.norm_scale,                        # RMSNorm gains
+    "pca": W.identity,                            # until calibrated
+}
+
+
+def program_fields(cfg: dict, resolved) -> Dict[str, Tuple[str, object]]:
+    """The fields of the program's model config that the file sets, each
+    as (the file's key, value). ``resolved`` is the program's own config
+    of the architecture; an RMSNorm epsilon is set where it has one."""
+    out = {"n_layers": ("num_hidden_layers", cfg["num_hidden_layers"]),
+           "d_model": ("hidden_size", cfg["hidden_size"]),
+           "n_heads": ("num_attention_heads", cfg["num_attention_heads"]),
+           "n_kv_heads": ("num_key_value_heads",
+                          cfg["num_key_value_heads"]),
+           "d_ff": ("intermediate_size", cfg["intermediate_size"]),
+           "vocab": ("vocab_size", cfg["vocab_size"]),
+           "rope_theta": ("rope_theta", float(cfg["rope_theta"]))}
+    if hasattr(resolved, "norm_eps"):
+        out["norm_eps"] = ("rms_norm_eps", float(cfg["rms_norm_eps"]))
+    return out
+
+
+def departures(pc, cfg: dict, norm_eps: float) -> List[str]:
+    """Where the program's model config ``pc``, serving with RMSNorm
+    epsilon ``norm_eps``, is not the Qwen2 of the file."""
+    out = []
+    if not (pc.qkv_bias and pc.mlp == "swiglu" and pc.norm == "rms"
+            and pc.rope):
+        out.append("not Qwen2's block (qkv bias, SwiGLU, RMSNorm, rope)")
+    if pc.resolved_head_dim * pc.n_heads != pc.d_model:
+        out.append(f"head_dim {pc.resolved_head_dim} x {pc.n_heads} "
+                   f"heads is not d_model {pc.d_model}")
+    if norm_eps != float(cfg["rms_norm_eps"]):
+        out.append(f"RMSNorm epsilon {norm_eps}, the file "
+                   f"{cfg['rms_norm_eps']}")
+    if not cfg["tie_word_embeddings"]:
+        out.append("the program's embedding table is its head; the file "
+                   "unties them")
+    return out
 
 
 def _fp8(x, axis):

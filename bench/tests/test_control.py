@@ -11,7 +11,7 @@ from bench.lib import harness, spec
 
 @pytest.mark.parametrize("case", sorted(tiny.SIZES))
 def test_float8_control_fails_the_limit(case):
-    limit = spec.load_cell(tiny.CELL).limits[harness.CHECK]["limit"]
+    limit = spec.load_cell(tiny.CELLS[case]).limits[harness.CHECK]["limit"]
     keep = {}
     # every served request is compared: a tiny model's logits spread less
     # than the cell's, so the control needs as many positions as it gets

@@ -62,3 +62,20 @@ def test_roofline_share_names_the_bound():
     assert bound == "memory" and abs(share - 50.0) < 1e-9
     share, bound = flops.roofline_share(197e9, 1.0, 4e-3, 197e12, 819e9)
     assert bound == "compute" and abs(share - 25.0) < 1e-9
+
+
+def test_prefill_chunk_flops_by_hand():
+    # qwen2.5-14b-pp4: 12 layers, 40 query and 8 KV heads of 128, d_ff
+    # 13824; per layer a token multiplies 275,251,200 weights
+    with open(os.path.join(ROOT, "bench/configs/qwen2.5-14b-pp4.json")) as f:
+        m = flops.dims(json.load(f), smax=3584)
+    assert flops.layer_matmul_params(m) == 275_251_200
+    # a full chunk of 256 tokens at positions 512..767: each attends to
+    # 513..768 keys, 256 * 512 + 256 * 257 / 2 = 163,968 in all
+    want = (2 * 256 * 12 * 275_251_200 + 2 * 12 * 256 * 48 * 128 * 128
+            + 4 * 12 * 40 * 128 * 163_968)
+    assert flops.prefill_chunk_flops(m, 512, 256) == want
+    # the last, partial chunk of a prompt: 3 tokens from position 0
+    assert flops.prefill_chunk_flops(m, 0, 3) == (
+        2 * 3 * 12 * 275_251_200 + 2 * 12 * 3 * 48 * 128 * 128
+        + 4 * 12 * 40 * 128 * 6)

@@ -31,7 +31,7 @@ def test_sessions_same_sizes_any_seed():
 def test_unknown_kind_and_distribution_raise():
     tr = mix("longctx_decode")
     with pytest.raises(ValueError):
-        traffic.plan({**tr, "kind": "open"}, 1, 1000)
+        traffic.plan({**tr, "kind": "bursts"}, 1, 1000)
     with pytest.raises(ValueError):
         traffic.plan({**tr, "prompt_len": {"dist": "zipf", "min": 1,
                                            "max": 2}}, 1, 1000)

@@ -5,14 +5,16 @@ CPU serves (per-head selection) and the Pallas kernel's group selection
 agree.
 
 Each case is a traffic mix of ``bench/traffic`` at tiny sizes, served by
-the configuration of cell ``CELL``."""
+the configuration of its cell (``CELLS``)."""
 import json
 import os
 import time
 
 from bench.lib import harness, spec
 
-CELL = "qwen2.5-3b.longctx_decode"
+CELLS = {"longctx_decode": "qwen2.5-3b.longctx_decode",
+         "chat_poisson": "qwen2.5-14b-pp4.chat_poisson"}
+CELL = CELLS["longctx_decode"]
 CONFIG = {"num_hidden_layers": 2, "hidden_size": 256,
           "num_attention_heads": 2, "num_key_value_heads": 2,
           "intermediate_size": 512, "vocab_size": 4096,
@@ -21,6 +23,11 @@ SIZES = {
     "longctx_decode": {
         "sessions": 3, "prompt_len": {"min": 300, "max": 600},
         "engine": {"n_slots": 3, "smax": 1024}},
+    "chat_poisson": {
+        "rate": 5.0, "warm_seconds": 1.0,
+        "prompt_len": {"median": 200, "min": 32, "max": 600},
+        "max_new": {"median": 24, "min": 8, "max": 64},
+        "engine": {"n_slots": 4, "smax": 1024}},
 }
 
 
@@ -35,5 +42,6 @@ def run(case, seed, seconds=2.0, config=None, traffic=None, **kw):
     ``kw`` goes to ``harness.run`` (``keep``, ``control``)."""
     over = {"config": harness._merge(CONFIG, config),
             "traffic": harness._merge(mix(case), traffic)}
-    return harness.run(CELL, seed, seconds, False, t_start=time.time(),
-                       require_chip=False, overrides=over, **kw)
+    return harness.run(CELLS[case], seed, seconds, False,
+                       t_start=time.time(), require_chip=False,
+                       overrides=over, **kw)
